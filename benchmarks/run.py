@@ -129,16 +129,21 @@ def roofline_summary() -> None:
     print(f"roofline_summary,{n_ok},fit16GB={n_fit} worst_tx={worst[1]}")
 
 
-def main() -> None:
+def main() -> int:
+    """Run every entry; an entry that raises prints an ``ERROR`` row, the
+    rest still run, and the exit code is 1 if any row errored."""
     print("name,us_per_call,derived")
+    n_err = 0
     for fn in (table1_de_gen, fig4_lite, executor_eval, fused_de_island,
                de_kernel_parity, roofline_summary):
         try:
             fn()
         except Exception as e:  # keep the harness running
+            n_err += 1
             print(f"{fn.__name__},nan,ERROR:{type(e).__name__}:{e}",
                   file=sys.stdout)
+    return 1 if n_err else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

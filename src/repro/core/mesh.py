@@ -92,16 +92,10 @@ def ring_perm(n_shards: int) -> list[tuple[int, int]]:
 
 
 def shard_map(f: Callable, mesh: Mesh, in_specs, out_specs) -> Callable:
-    """Version-portable ``shard_map`` (replication checking off): jax >= 0.5
-    exposes ``jax.shard_map`` with ``check_vma``; the 0.4.x line the repo
-    supports only has ``jax.experimental.shard_map.shard_map`` with
-    ``check_rep``. Every engine/executor shard_map goes through here."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking off (``check_vma=False``),
+    the one setting every engine/executor shard_map shares."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def host_device_count() -> int:

@@ -126,6 +126,38 @@ class _RunItem:
     store_dir: str | None = None   # resumed runs keep their original dir
 
 
+def build_optimizer(req: OptRequest,
+                    exec_cfg: ExecutorConfig = ExecutorConfig(),
+                    mesh: Mesh | None = None) -> IslandOptimizer:
+    """The engine a request's shape-class runs on — what the scheduler
+    builds per bucket, and what a standalone ``minimize`` of the same
+    request must use to reproduce a served job (DESIGN.md §5)."""
+    from repro.core import ALGORITHMS  # late: core/__init__ imports us
+    cfg = IslandConfig(
+        n_islands=req.n_islands, pop=req.pop, dim=req.dim,
+        sync_every=req.sync_every, migration=req.migration,
+        n_migrants=req.n_migrants, share_incumbent=req.share_incumbent,
+        max_evals=req.max_evals, polish=req.polish,
+        polish_every=req.polish_every, polish_topk=req.polish_topk,
+        polish_steps=req.polish_steps, portfolio=req.portfolio,
+        sync_policy=req.sync_policy, max_staleness=req.max_staleness,
+    )
+    # Portfolio requests (DESIGN.md §10) run heterogeneous per-island
+    # policies: `algo` is ignored and `params` maps policy name -> kwargs
+    # (build_portfolio thaws the frozen pair-tuples).
+    maker = None if req.portfolio else ALGORITHMS[req.algo]
+    # Sharded requests (devices > 1, DESIGN.md §8) get their own island mesh;
+    # MeshConfig.build raises inside flush_bucket's fault isolation when the
+    # host lacks the devices, so one impossible request cannot take the
+    # service down.
+    mesh_cfg = MeshConfig(devices=req.devices) if req.devices > 1 else None
+    return IslandOptimizer(
+        maker, cfg, params=dict(req.params),
+        mesh=None if mesh_cfg is not None else mesh, mesh_cfg=mesh_cfg,
+        exec_cfg=dataclasses.replace(exec_cfg, backend=req.backend),
+    )
+
+
 class ShapeBucketScheduler:
     """Accepts many concurrent OptRequests, runs each shape-class as one
     jobs-axis dispatch.
@@ -245,33 +277,7 @@ class ShapeBucketScheduler:
             key = req.shape_class()
             opt = self._lru_get(self._optimizers, key)
             if opt is None:
-                from repro.core import ALGORITHMS  # late: core/__init__ imports us
-                cfg = IslandConfig(
-                    n_islands=req.n_islands, pop=req.pop, dim=req.dim,
-                    sync_every=req.sync_every, migration=req.migration,
-                    n_migrants=req.n_migrants, share_incumbent=req.share_incumbent,
-                    max_evals=req.max_evals, polish=req.polish,
-                    polish_every=req.polish_every, polish_topk=req.polish_topk,
-                    polish_steps=req.polish_steps, portfolio=req.portfolio,
-                    sync_policy=req.sync_policy,
-                    max_staleness=req.max_staleness,
-                )
-                # Portfolio requests (DESIGN.md §10) run heterogeneous per-island
-                # policies: `algo` is ignored and `params` maps policy name ->
-                # kwargs (build_portfolio thaws the frozen pair-tuples).
-                maker = None if req.portfolio else ALGORITHMS[req.algo]
-                # Sharded requests (devices > 1, DESIGN.md §8) get their own
-                # island mesh; MeshConfig.build raises inside flush_bucket's
-                # fault isolation when the host lacks the devices, so one
-                # impossible request cannot take the service down.
-                mesh_cfg = (MeshConfig(devices=req.devices)
-                            if req.devices > 1 else None)
-                opt = IslandOptimizer(
-                    maker, cfg, params=dict(req.params),
-                    mesh=None if mesh_cfg is not None else self.mesh,
-                    mesh_cfg=mesh_cfg,
-                    exec_cfg=dataclasses.replace(self.exec_cfg, backend=req.backend),
-                )
+                opt = build_optimizer(req, self.exec_cfg, self.mesh)
                 self._lru_put(self._optimizers, key, opt)
             return opt
 

@@ -8,8 +8,9 @@ the repo already carries:
 
   * candidate ``(pop_block, dim_pad)`` configs are scored with the roofline
     terms of ``parallel.roofline`` (compute = FLOPs / peak, memory = HBM
-    bytes / bandwidth — same constants the dry-run analyzer uses) built from
-    a per-kernel operand profile (``KIND_PROFILES``);
+    bytes / bandwidth, from the attached chip's ``DEVICE_PEAKS`` row, as
+    the dry-run analyzer uses) built from a per-kernel operand profile
+    (``KIND_PROFILES``);
   * VMEM feasibility comes from ``parallel.memmodel.pallas_tile_bytes`` (the
     double-buffered working set of one grid step must fit the budget);
   * off-TPU the kernels run in Pallas *interpret* mode, where every grid
@@ -37,9 +38,13 @@ import numpy as np
 
 import jax
 
-from repro.models.config import HBM_BW, PEAK_FLOPS_BF16
 from repro.parallel.memmodel import pallas_tile_bytes
-from repro.parallel.roofline import Roofline
+from repro.parallel.roofline import (DEVICE_PEAKS, REHEARSAL_KIND, Roofline,
+                                     device_peaks)
+
+# Rates of the rehearsal row, which scores every tile chosen off the TPU.
+PEAK_FLOPS_BF16 = DEVICE_PEAKS[REHEARSAL_KIND].flops_bf16
+HBM_BW = DEVICE_PEAKS[REHEARSAL_KIND].hbm_bw
 
 # -- the threaded config -----------------------------------------------------
 
@@ -151,8 +156,9 @@ def predict(kind: str, P: int, D: int, pop_block: int, dim_pad: int,
     """Roofline prediction for one ``(pop_block, dim_pad)`` candidate.
 
     FLOPs and HBM bytes come from the kernel's operand profile over the
-    padded ``(Pp, dim_pad)`` problem; time terms use the same peak numbers as
-    ``parallel.roofline.analyze``. Interpret mode adds a per-grid-step
+    padded ``(Pp, dim_pad)`` problem; time terms use the attached chip's row
+    of ``parallel.roofline.DEVICE_PEAKS`` (the v5e row off the TPU), as
+    ``parallel.roofline.analyze`` does. Interpret mode adds a per-grid-step
     dispatch overhead, which is what drives off-TPU configs toward one big
     tile while VMEM keeps TPU tiles small.
     """
@@ -167,8 +173,9 @@ def predict(kind: str, P: int, D: int, pop_block: int, dim_pad: int,
         + prof.row * Pp * 4
         + prof.bcast * dim_pad * 4
     )
-    t_c = flops / PEAK_FLOPS_BF16
-    t_m = hbm / HBM_BW
+    peaks = device_peaks()
+    t_c = flops / peaks.flops_bf16
+    t_m = hbm / peaks.hbm_bw
     tile = pallas_tile_bytes(
         prof.vec_in + prof.vec_out, pop_block, dim_pad,
         n_row=prof.row, n_bcast=prof.bcast, itemsize=4,  # VMEM tiles are f32

@@ -61,7 +61,12 @@ def _eval_tile(x: jax.Array, fn: str, dim: int, bias: float) -> jax.Array:
     if fn == "griewank":
         s = jnp.where(valid, x * x, 0.0).sum(axis=1) / 4000.0
         i = jnp.sqrt((lane + 1).astype(jnp.float32))
-        p = jnp.where(valid, jnp.cos(x / i), 1.0).prod(axis=1)
+        c = jnp.where(valid, jnp.cos(x / i), 1.0)
+        # Mosaic has no reduce_prod: |prod c| = exp(sum log|c|), and the sign
+        # is the parity of the count of negative factors.
+        n_neg = jnp.where(c < 0.0, 1.0, 0.0).sum(axis=1)
+        mag = jnp.exp(jnp.log(jnp.abs(c)).sum(axis=1))
+        p = jnp.where(n_neg - 2.0 * jnp.floor(0.5 * n_neg) > 0.5, -mag, mag)
         return s - p + 1.0 + bias
     if fn == "schwefel":
         t = jnp.where(valid, x * jnp.sin(jnp.sqrt(jnp.abs(x))), 0.0)
@@ -104,7 +109,7 @@ def _kernel(x_ref, shift_ref, o_ref, *, fn: str, dim: int, bias: float,
     # Pad rows from the pop_block round-up carry +inf fitness so they can
     # never be selected downstream (satellite: no clamp-overlap reliance).
     row_ok = _row_index(x.shape[0]) < n_rows
-    o_ref[...] = jnp.where(row_ok, fit, jnp.inf).astype(o_ref.dtype)
+    o_ref[...] = jnp.where(row_ok, fit, jnp.inf)[:, None].astype(o_ref.dtype)
 
 
 def bench_eval(pop: jax.Array, fn: str, shift: jax.Array | None = None,
@@ -140,8 +145,10 @@ def bench_eval(pop: jax.Array, fn: str, shift: jax.Array | None = None,
             pl.BlockSpec((cfg.pop_block, Dp), lambda i: (i, 0)),
             pl.BlockSpec((1, Dp), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((cfg.pop_block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Pp,), jnp.float32),
+        # A (Pp, 1) column, like the other kernels' fitness outputs: Mosaic
+        # refuses a rank-1 block that is neither 128-aligned nor the whole array.
+        out_specs=pl.BlockSpec((cfg.pop_block, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Pp, 1), jnp.float32),
         interpret=cfg.interpret,
     )(x, s[None, :])
-    return out[:P]
+    return out[:P, 0]

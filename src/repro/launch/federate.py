@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import glob
 import json
 import os
 import re
@@ -49,7 +50,7 @@ import socket
 import subprocess
 import sys
 import time
-from typing import Any, IO
+from typing import Any, IO, Mapping
 
 _LISTEN_RE = re.compile(r"listening on ([\w\.\-]+):(\d+)")
 
@@ -209,6 +210,31 @@ class _Worker:
                 self.proc.wait()
 
 
+def host_chip_count() -> int:
+    """TPU chips this host exposes, counted from their device nodes
+    (``/dev/accel<n>``, ``/dev/vfio/<n>``) so the coordinator never loads
+    JAX itself."""
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            + len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def check_chip_workers(n_workers: int, env: Mapping[str, str]) -> None:
+    """Refuse to start more chip-holding workers than a host allows.
+
+    A worker whose ``JAX_PLATFORMS`` leaves the TPU in play takes every chip
+    it can see, and a chip belongs to one process at a time; so on a host
+    with chips at most one such worker may run. CPU workers
+    (``JAX_PLATFORMS=cpu``) hold no chip and are not limited."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    on_chip = not platforms or "tpu" in platforms.split(",")
+    chips = host_chip_count()
+    if on_chip and chips and n_workers > 1:
+        raise ValueError(
+            f"{n_workers} opt_serve workers would share this host's {chips} "
+            f"TPU chip(s), but each worker takes every chip it sees: run one "
+            f"worker per host, or set JAX_PLATFORMS=cpu for CPU workers")
+
+
 def _wait_listening(stderr: IO[bytes], timeout: float = 120.0) -> int:
     """Parse the worker's ephemeral port from its ``listening on`` banner
     (the resume summary line, when present, precedes it)."""
@@ -241,7 +267,10 @@ class FederationCoordinator:
     # -- lifecycle ---------------------------------------------------------
 
     def start(self) -> None:
-        """Spawn every worker process and wait for their TCP banners."""
+        """Spawn every worker process and wait for their TCP banners. Raises
+        ``ValueError`` before spawning anything when the workers would share
+        a chip (:func:`check_chip_workers`)."""
+        check_chip_workers(len(self.workers), os.environ)
         for w in self.workers:
             os.makedirs(w.ckpt_dir, exist_ok=True)
             w.spawn()

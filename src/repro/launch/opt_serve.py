@@ -95,6 +95,7 @@ from typing import Any
 from repro.core.api import OptRequest
 from repro.core.scheduler import (SchedulerOverloaded, ShapeBucketScheduler,
                                   UnknownJob)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 class OptimizationService:
@@ -316,6 +317,7 @@ def main() -> None:
                          "(also becomes the checkpoint dir unless one is set)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     ckpt = args.checkpoint_dir or args.resume_dir
     service = OptimizationService(
         max_batch=args.max_batch, flush_ms=args.flush_ms,

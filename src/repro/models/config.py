@@ -146,8 +146,3 @@ class ModelConfig:
         small.update(overrides)
         return dataclasses.replace(self, **small)
 
-
-# hardware model for roofline math (TPU v5e-like, per assignment constants)
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link

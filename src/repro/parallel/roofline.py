@@ -18,7 +18,43 @@ from __future__ import annotations
 import dataclasses
 import re
 
-from repro.models.config import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+import jax
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peak rates of one accelerator kind."""
+
+    flops_bf16: float     # FLOP/s, bf16
+    hbm_bw: float         # HBM bytes/s
+    hbm_bytes: float      # HBM capacity, bytes
+    ici_bw: float         # interconnect bytes/s per link
+
+
+# Keyed by jax's ``Device.device_kind``. Source: Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect (50 GB/s on each of four links).
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops_bf16=197e12, hbm_bw=819e9,
+                               hbm_bytes=16e9, ici_bw=50e9),
+}
+# Off the TPU (interpret mode, compiles for a described chip) costs are
+# rehearsed against this row.
+REHEARSAL_KIND = "TPU v5 lite"
+
+
+def device_peaks() -> DevicePeaks:
+    """Peaks of the attached TPU, or the v5e rehearsal row off the TPU. A TPU
+    whose kind is not in :data:`DEVICE_PEAKS` raises ``KeyError``."""
+    if jax.default_backend() != "tpu":
+        return DEVICE_PEAKS[REHEARSAL_KIND]
+    kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise KeyError(f"no peak rates for device kind {kind!r}; known: "
+                       f"{sorted(DEVICE_PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -320,9 +356,10 @@ def analyze(compiled, hlo_text: str | None = None) -> Roofline:
     costs = hlo_costs(text)
     flops = costs.flops
     hbm = costs.bytes_accessed
-    tc = flops / PEAK_FLOPS_BF16
-    tm = hbm / HBM_BW
-    tx = coll.total_bytes / ICI_BW
+    peaks = device_peaks()
+    tc = flops / peaks.flops_bf16
+    tm = hbm / peaks.hbm_bw
+    tx = coll.total_bytes / peaks.ici_bw
     terms = {"compute": tc, "memory": tm, "collective": tx}
     mem = compiled.memory_analysis()
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes

@@ -15,6 +15,7 @@ import time
 import numpy as np
 import pytest
 
+from repro.launch import federate as fed
 from repro.launch.federate import (FederationConfig, FederationCoordinator,
                                    WorkerSpec, federate)
 
@@ -77,3 +78,19 @@ def test_federation_survives_sigkilled_worker(tmp_path):
     assert res.arg == ref.arg
     assert [[r["value"] for r in leg] for leg in res.legs] == \
            [[r["value"] for r in leg] for leg in ref.legs]
+
+
+def test_federation_refuses_workers_sharing_a_chip(tmp_path, monkeypatch):
+    """Two workers that would both load the TPU on a one-chip host are
+    refused before any process starts; CPU workers and a lone chip worker
+    pass the guard."""
+    monkeypatch.setattr(fed, "host_chip_count", lambda: 1)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    coord = FederationCoordinator(_cfg(tmp_path, "chips"))
+    with pytest.raises(ValueError, match=r"2 opt_serve workers .* 1 TPU chip"):
+        coord.start()
+    assert all(w.proc is None for w in coord.workers)
+    fed.check_chip_workers(2, {"JAX_PLATFORMS": "cpu"})
+    fed.check_chip_workers(1, {})
+    monkeypatch.setattr(fed, "host_chip_count", lambda: 0)
+    fed.check_chip_workers(2, {})                  # no chip: nothing to share

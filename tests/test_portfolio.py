@@ -75,9 +75,7 @@ def test_unified_state_shares_one_pytree_structure():
     for name, spec in pf.REGISTRY.items():
         algo = spec.maker(f=f, evaluator=ev, pop=8, dim=4)
         u = pf.UnifiedPolicy(spec, algo, 8, 4).init(KEY)
-        structs.add(jax.tree.structure(u)
-                    if hasattr(jax.tree, "structure")
-                    else jax.tree_util.tree_structure(u))
+        structs.add(jax.tree.structure(u))
         assert u["alive"].dtype == jnp.bool_ and u["alive"].shape == (8,)
     assert len(structs) == 1
 

@@ -623,3 +623,26 @@ def test_portfolio_normalizes_unused_algo_out_of_the_key():
     c = OptRequest.from_dict({"fn": "sphere", "n_islands": 4,
                               "portfolio": ["de", "sa"]})
     assert a.shape_class() != c.shape_class()
+
+
+def test_compile_cache_dir_is_the_env_or_a_fixed_checkout_path(monkeypatch):
+    """``opt_serve``'s compile cache: ``JAX_COMPILATION_CACHE_DIR`` when set
+    (JAX reads it; nothing is overridden), else ``.jax_cache`` at the root of
+    the checkout, the same path on every call; off on the CPU backend. The
+    config writes are intercepted, so this test turns no cache on."""
+    from pathlib import Path
+    from repro.launch import compile_cache as cc
+    updates = []
+    monkeypatch.setattr(cc.jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    monkeypatch.setattr(cc.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv(cc.CACHE_ENV, "/cache/from/env")
+    assert cc.enable_compile_cache() == "/cache/from/env" and updates == []
+    monkeypatch.delenv(cc.CACHE_ENV)
+    repo = Path(__file__).resolve().parents[1]
+    path = cc.enable_compile_cache()
+    assert path == str(repo / ".jax_cache") == cc.enable_compile_cache()
+    assert updates == [("jax_compilation_cache_dir", path)] * 2
+    updates.clear()
+    monkeypatch.setattr(cc.jax, "default_backend", lambda: "cpu")
+    assert cc.enable_compile_cache() is None and updates == []

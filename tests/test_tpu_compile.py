@@ -1,0 +1,103 @@
+"""Compile the five fused kernels at Table I width (800x1000) for a described
+TPU v5e chip, with the tile the autotuner picks for the TPU.
+
+No chip is needed: the TPU compiler runs here against a described topology
+and raises what Mosaic would raise on the chip (tiling, layouts, VMEM, and
+primitives it cannot lower). Every case must compile to a real Mosaic kernel
+(``tpu_custom_call``), never to interpreted ops.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU's library, and every test worker imports
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import autotune
+from repro.kernels.bench_eval import bench_eval
+from repro.kernels.de_step import de_step
+from repro.kernels.eval_select import eval_select
+from repro.kernels.ga_step import ga_step
+from repro.kernels.pso_step import pso_step
+
+P, D = 800, 1000          # Table I: population 800, 1000-D
+
+CASES = ([(k, t) for k in ("bench_eval", "de_step")
+          for t in ("shifted_rosenbrock", "rastrigin", "griewank")]
+         + [(k, "rastrigin") for k in ("pso_step", "ga_step", "eval_select")])
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2, with JAX's persistent cache off
+    around the compiles (an entry written for a described chip cannot be
+    read back without one)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+def _entry(kind, tag, kc):
+    """(kernel call, operand shapes) for one kernel at (P, D)."""
+    f32, i32 = jnp.float32, jnp.int32
+    pd, p, d = ((P, D), f32), ((P,), f32), ((D,), f32)
+    if kind == "bench_eval":
+        return (lambda x, s: bench_eval(x, tag, shift=s, kernel_cfg=kc),
+                [pd, d])
+    if kind == "de_step":
+        return (lambda *a: de_step(*a[:-1], fn=tag, shift=a[-1],
+                                   kernel_cfg=kc),
+                [pd, p, ((3, P), i32), pd, ((P,), i32), d])
+    if kind == "pso_step":
+        return (lambda *a: pso_step(*a[:-1], fn=tag, shift=a[-1],
+                                    kernel_cfg=kc),
+                [pd, pd, pd, p, pd, pd, d, d])
+    if kind == "ga_step":
+        return (lambda *a: ga_step(*a[:-1], fn=tag, shift=a[-1],
+                                   kernel_cfg=kc),
+                [pd, pd, pd, p, ((P,), i32), p, pd, pd, d])
+    return (lambda *a: eval_select(*a[:-1], fn=tag, shift=a[-1],
+                                   kernel_cfg=kc),
+            [pd, p, pd, p, d])
+
+
+@pytest.mark.parametrize("kind,tag", CASES)
+def test_kernel_compiles_for_v5e(kind, tag, one_chip):
+    kc = autotune.choose(kind, P, D, tag, interpret=False)
+    assert kc.interpret is False
+    call, shapes = _entry(kind, tag, kc)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, (kind, tag, kc)
+
+
+def test_tile_scores_use_the_attached_tpu_row(monkeypatch):
+    """On a TPU the autotuner prices tiles with the chip's own row of the
+    peak table; a kind missing from the table is an error, not a default."""
+    from repro.parallel import roofline
+
+    class Chip:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(roofline.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(roofline.jax, "devices", lambda: [Chip()])
+    assert roofline.device_peaks() == roofline.DEVICE_PEAKS["TPU v5 lite"]
+    autotune.predict("de_step", P, D, 8, 1024, tag="rastrigin")
+    Chip.device_kind = "TPU v99"
+    with pytest.raises(KeyError, match="TPU v99"):
+        autotune.predict("de_step", P, D, 8, 1024, tag="rastrigin")
