@@ -34,7 +34,6 @@ import json
 import os
 import sys
 import tempfile
-import time
 
 import jax
 import jax.numpy as jnp
@@ -166,18 +165,13 @@ def kernels_phase(P: int, D: int) -> None:
     names = ["shifted_rosenbrock", "rastrigin"]
     if kreg.supported("griewank"):
         names.append("griewank")
-    t_compile = t_run = 0.0
     for kind in KERNELS:
         for name in names:
             f = get(name, D)
             entry, reference, args = kernel_case(kind, f, P, D,
                                                  jax.random.PRNGKey(7))
-            t0 = time.perf_counter()
             compiled = jax.jit(entry).lower(*args).compile()
-            t1 = time.perf_counter()
             out = jax.block_until_ready(compiled(*args))
-            t2 = time.perf_counter()
-            t_compile, t_run = t_compile + t1 - t0, t_run + t2 - t1
             check("tpu_custom_call" in compiled.as_text(),
                   f"{kind}/{name}: no Mosaic kernel in the compiled program")
             expect = reference(*args)
@@ -195,10 +189,8 @@ def kernels_phase(P: int, D: int) -> None:
                           f"{kind}/{name}: non-finite output")
             err = rel_err([o for o in outs if o.dtype != jnp.bool_],
                           [e for e in exps if e.dtype != jnp.bool_])
-            log(f"kernels: {kind:11s} {name:18s} {P}x{D} max_rel_err={err!r} "
-                f"compile_s={t1 - t0:.3f} run_s={t2 - t1:.4f}")
+            log(f"kernels: {kind:11s} {name:18s} {P}x{D} max_rel_err={err!r}")
             check(err <= RTOL, f"{kind}/{name}: max rel err {err} > {RTOL}")
-    log(f"phase kernels: compile_s={t_compile:.3f} run_s={t_run:.3f}")
 
 
 # -- Table I -----------------------------------------------------------------
@@ -237,14 +229,10 @@ def table1_phase(P: int, D: int, gens: int) -> None:
         opt = IslandOptimizer(ALGORITHMS["de"], cfg, params={**base, **extra},
                               exec_cfg=ExecutorConfig(backend=backend))
         with mosaic_programs() as mosaic:
-            t0 = time.perf_counter()
             r = opt.minimize(f, key)
-            t1 = time.perf_counter()
         again = opt.minimize(f, key)
-        t2 = time.perf_counter()
         log(f"table1: {name:15s} value={r.value!r} gens={r.n_gens} "
-            f"evals={r.n_evals} compile_s={(t1 - t0) - (t2 - t1):.3f} "
-            f"run_s={t2 - t1:.3f} mosaic_programs={len(mosaic)}")
+            f"evals={r.n_evals} mosaic_programs={len(mosaic)}")
         check(np.isfinite(r.value), f"table1/{name}: value {r.value}")
         check(r.value < init_best,
               f"table1/{name}: {r.value} does not improve on {init_best}")
@@ -329,7 +317,6 @@ def compare_to_standalone(cls: str, req: dict, served: dict) -> None:
     r0 = OptRequest.from_dict(dict(req, seed=SEEDS[0]))
     opt = build_optimizer(r0)
     f = get(r0.fn, r0.dim)
-    t0 = time.perf_counter()
     n_same, max_diff, first_part = 0, 0.0, None
     for seed in SEEDS:
         ref = opt.minimize(f, jax.random.PRNGKey(seed))
@@ -343,8 +330,7 @@ def compare_to_standalone(cls: str, req: dict, served: dict) -> None:
             part = int(np.argmax(hs != hr)) if np.any(hs != hr) else len(hs)
             first_part = part if first_part is None else min(first_part, part)
     log(f"served: {cls:17s} bit_identical={n_same}/{len(SEEDS)} "
-        f"max_final_diff={max_diff!r} first_round_parted={first_part} "
-        f"standalone_s={time.perf_counter() - t0:.3f}")
+        f"max_final_diff={max_diff!r} first_round_parted={first_part}")
 
 
 def served_phase(dim: int, pop: int) -> None:
@@ -356,11 +342,8 @@ def served_phase(dim: int, pop: int) -> None:
                                   flush_ms=50.0)
     try:
         with mosaic_programs() as mosaic:
-            t0 = time.perf_counter()
             served = serve_jobs(service, classes)
-            t1 = time.perf_counter()
-        again = serve_jobs(service, classes)    # compiled: the run alone
-        t2 = time.perf_counter()
+        again = serve_jobs(service, classes)
         check(service.handle({"op": "quit"}) == {"bye": True}, "served: quit")
     finally:
         service.scheduler.close()
@@ -376,8 +359,6 @@ def served_phase(dim: int, pop: int) -> None:
             f"values={vals!r}")
     for cls, req in classes.items():
         compare_to_standalone(cls, req, served)
-    log(f"phase served: compile_s={(t1 - t0) - (t2 - t1):.3f} "
-        f"run_s={t2 - t1:.3f}")
 
 
 # -- four chips ----------------------------------------------------------------
@@ -395,21 +376,18 @@ def sharded_phase(dim: int, pop: int, devices: int) -> None:
                 n_islands=8, migration="ring", sync_every=10,
                 max_evals=8 * pop * 101, seed=0)
     service = OptimizationService(workers=2, max_batch=1, flush_ms=50.0)
-    replies, times = {}, {}
+    replies = {}
     try:
         for n in (devices, 1):
-            t0 = time.perf_counter()
             sub = service.handle({"op": "submit",
                                   "request": dict(base, devices=n)})
             check("error" not in sub, f"sharded/devices={n}: {sub}")
             replies[n] = service.handle({"op": "result", "id": sub["id"]})
-            times[n] = time.perf_counter() - t0
             r = replies[n]
             check(r.get("status") == "done", f"sharded/devices={n}: {r}")
             check(np.isfinite(r["value"]), f"sharded/devices={n}: {r}")
             log(f"sharded: devices={n} value={r['value']!r} "
-                f"gens={r['n_gens']} evals={r['n_evals']} "
-                f"compile_and_run_s={times[n]:.3f}")
+                f"gens={r['n_gens']} evals={r['n_evals']}")
         service.handle({"op": "quit"})
     finally:
         service.scheduler.close()
@@ -453,14 +431,12 @@ def main(argv: list[str] | None = None) -> int:
     from repro.launch.compile_cache import enable_compile_cache
 
     log(f"compile cache: {enable_compile_cache()}")
-    t0 = time.perf_counter()
     if args.chips == 4:
         sharded_phase(CONFIG.dim, CONFIG.pop, devices=4)
     else:
         kernels_phase(CONFIG.pop, CONFIG.dim)
         table1_phase(CONFIG.pop, CONFIG.dim, TABLE1_GENS)
         served_phase(CONFIG.dim, CONFIG.pop)
-    log(f"total_s={time.perf_counter() - t0:.3f}")
     print(json.dumps({"ok": True, "device": info}), flush=True)
     return 0
 

@@ -12,6 +12,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.islands import MetaHeuristic, State, clip_box, uniform_init
 from repro.functions.benchmarks import Function
 
@@ -43,32 +44,37 @@ def make(
     def local_search(y: Array, fy: Array, key: Array):
         def body(c, carry):
             y, fy = carry
-            k = jax.random.fold_in(key, c)
-            step = step0 * (ls_shrink ** c)
-            y2 = clip_box(y + step * jax.random.normal(k, y.shape), lo, hi)
+            with obs.scope(obs.VARIATION):
+                k = jax.random.fold_in(key, c)
+                step = step0 * (ls_shrink ** c)
+                y2 = clip_box(y + step * jax.random.normal(k, y.shape), lo, hi)
             fy2 = evaluator(y2)
-            imp = fy2 < fy
-            return jnp.where(imp[:, None], y2, y), jnp.where(imp, fy2, fy)
+            with obs.scope(obs.SELECT):
+                imp = fy2 < fy
+                return jnp.where(imp[:, None], y2, y), jnp.where(imp, fy2, fy)
 
         return jax.lax.fori_loop(0, n_ls, body, (y, fy))
 
     def gen(state: State, key: Array) -> State:
         x, fx = state["pop"], state["fit"]
-        kk, kl, ka = jax.random.split(key, 3)
-        y = clip_box(x + kick * jax.random.normal(kk, x.shape), lo, hi)
+        with obs.scope(obs.VARIATION):
+            kk, kl, ka = jax.random.split(key, 3)
+            y = clip_box(x + kick * jax.random.normal(kk, x.shape), lo, hi)
         fy = evaluator(y)
         y, fy = local_search(y, fy, kl)
-        dF = fy - fx
-        accept = (dF <= 0) | (jax.random.uniform(ka, fx.shape) < jnp.exp(-dF / T))
-        x = jnp.where(accept[:, None], y, x)
-        fx = jnp.where(accept, fy, fx)
-        i = jnp.argmin(fx)
-        better = fx[i] < state["best_val"]
-        return {
-            "pop": x, "fit": fx,
-            "best_val": jnp.where(better, fx[i], state["best_val"]),
-            "best_arg": jnp.where(better, x[i], state["best_arg"]),
-        }
+        with obs.scope(obs.SELECT):
+            dF = fy - fx
+            accept = ((dF <= 0)
+                      | (jax.random.uniform(ka, fx.shape) < jnp.exp(-dF / T)))
+            x = jnp.where(accept[:, None], y, x)
+            fx = jnp.where(accept, fy, fx)
+            i = jnp.argmin(fx)
+            better = fx[i] < state["best_val"]
+            return {
+                "pop": x, "fit": fx,
+                "best_val": jnp.where(better, fx[i], state["best_val"]),
+                "best_arg": jnp.where(better, x[i], state["best_arg"]),
+            }
 
     return MetaHeuristic("bh", init, gen,
                          evals_per_gen=pop * (1 + n_ls), init_evals=pop)

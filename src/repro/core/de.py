@@ -28,6 +28,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.islands import MetaHeuristic, State, clip_box, track_best, uniform_init
 from repro.functions.benchmarks import Function
 from repro.kernels import registry as kreg
@@ -88,12 +89,15 @@ def make(
 
     def gen_sync(state: State, key: Array) -> State:
         p, fit = state["pop"], state["fit"]
-        trial = clip_box(_trials(p, state["best_arg"], key, w, px, strategy), lo, hi)
+        with obs.scope(obs.VARIATION):
+            trial = clip_box(_trials(p, state["best_arg"], key, w, px, strategy),
+                             lo, hi)
         tfit = evaluator(trial)
-        better = tfit <= fit
-        p = jnp.where(better[:, None], trial, p)
-        fit = jnp.where(better, tfit, fit)
-        return track_best(state, p, fit)
+        with obs.scope(obs.SELECT):
+            better = tfit <= fit
+            p = jnp.where(better[:, None], trial, p)
+            fit = jnp.where(better, tfit, fit)
+            return track_best(state, p, fit)
 
     csz = max(1, pop // n_chunks) if barrier_mode == "chunked" else pop
     n_eff_chunks = (pop + csz - 1) // csz
@@ -102,23 +106,27 @@ def make(
         # Later chunks read earlier chunks' already-updated vectors ("stale-ok").
         def body(c: int, carry: tuple[Array, Array]) -> tuple[Array, Array]:
             p, fit = carry
-            ck = jax.random.fold_in(key, c)
-            start = c * csz
-            trial_all = clip_box(
-                _trials(p, p[jnp.argmin(fit)], ck, w, px, strategy), lo, hi)
-            trial = jax.lax.dynamic_slice_in_dim(trial_all, start, csz, 0)
-            cur_f = jax.lax.dynamic_slice_in_dim(fit, start, csz, 0)
-            cur_p = jax.lax.dynamic_slice_in_dim(p, start, csz, 0)
+            with obs.scope(obs.VARIATION):
+                ck = jax.random.fold_in(key, c)
+                start = c * csz
+                trial_all = clip_box(
+                    _trials(p, p[jnp.argmin(fit)], ck, w, px, strategy), lo, hi)
+                trial = jax.lax.dynamic_slice_in_dim(trial_all, start, csz, 0)
+            with obs.scope(obs.SELECT):
+                cur_f = jax.lax.dynamic_slice_in_dim(fit, start, csz, 0)
+                cur_p = jax.lax.dynamic_slice_in_dim(p, start, csz, 0)
             tfit = evaluator(trial)
-            better = tfit <= cur_f
-            newp = jnp.where(better[:, None], trial, cur_p)
-            newf = jnp.where(better, tfit, cur_f)
-            p = jax.lax.dynamic_update_slice_in_dim(p, newp, start, 0)
-            fit = jax.lax.dynamic_update_slice_in_dim(fit, newf, start, 0)
+            with obs.scope(obs.SELECT):
+                better = tfit <= cur_f
+                newp = jnp.where(better[:, None], trial, cur_p)
+                newf = jnp.where(better, tfit, cur_f)
+                p = jax.lax.dynamic_update_slice_in_dim(p, newp, start, 0)
+                fit = jax.lax.dynamic_update_slice_in_dim(fit, newf, start, 0)
             return p, fit
 
         p, fit = jax.lax.fori_loop(0, n_eff_chunks, body, (state["pop"], state["fit"]))
-        return track_best(state, p, fit)
+        with obs.scope(obs.SELECT):
+            return track_best(state, p, fit)
 
     step_override = None
     if fused:
@@ -129,17 +137,21 @@ def make(
         def gen_fused(state: State, key: Array) -> State:
             # Same key discipline as gen_sync/_trials, so the fused and XLA
             # paths draw identical donors/crossover masks on a fixed seed.
-            ksel, kcr, kj = jax.random.split(key, 3)
-            ra, rb, rc = _distinct3(ksel, pop)
-            u = jax.random.uniform(kcr, (pop, dim))
-            jrand = jax.random.randint(kj, (pop,), 0, dim)
-            new_pop, new_fit = _de_step_kernel(
-                state["pop"], state["fit"], jnp.stack([ra, rb, rc]), u, jrand,
-                fn=spec.eval_tag, shift=f.shift, bias=f.bias,
-                w=w, px=px, lo=lo, hi=hi, interpret=interpret,
-                kernel_cfg=kernel_cfg,
-            )
-            return track_best(state, new_pop, new_fit)
+            with obs.scope(obs.VARIATION):
+                ksel, kcr, kj = jax.random.split(key, 3)
+                ra, rb, rc = _distinct3(ksel, pop)
+                u = jax.random.uniform(kcr, (pop, dim))
+                jrand = jax.random.randint(kj, (pop,), 0, dim)
+                donors = jnp.stack([ra, rb, rc])
+            with obs.scope(obs.FUSED):
+                new_pop, new_fit = _de_step_kernel(
+                    state["pop"], state["fit"], donors, u, jrand,
+                    fn=spec.eval_tag, shift=f.shift, bias=f.bias,
+                    w=w, px=px, lo=lo, hi=hi, interpret=interpret,
+                    kernel_cfg=kernel_cfg,
+                )
+            with obs.scope(obs.SELECT):
+                return track_best(state, new_pop, new_fit)
 
         step_override = gen_fused
 

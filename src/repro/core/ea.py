@@ -10,6 +10,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.islands import MetaHeuristic, State, clip_box, uniform_init
 from repro.functions.benchmarks import Function
 
@@ -40,27 +41,30 @@ def make(
 
     def gen(state: State, key: Array) -> State:
         x, fit, sigma = state["pop"], state["fit"], state["sigma"]
-        kp, km = jax.random.split(key)
-        parents = jax.random.randint(kp, (lam,), 0, pop)
-        child = clip_box(x[parents] + sigma * jax.random.normal(km, (lam, dim)), lo, hi)
+        with obs.scope(obs.VARIATION):
+            kp, km = jax.random.split(key)
+            parents = jax.random.randint(kp, (lam,), 0, pop)
+            child = clip_box(
+                x[parents] + sigma * jax.random.normal(km, (lam, dim)), lo, hi)
         cfit = evaluator(child)
 
-        # (mu + lambda) selection
-        allx = jnp.concatenate([x, child], axis=0)
-        allf = jnp.concatenate([fit, cfit], axis=0)
-        keep = jnp.argsort(allf)[:pop]
-        x, fit = allx[keep], allf[keep]
+        with obs.scope(obs.SELECT):
+            # (mu + lambda) selection
+            allx = jnp.concatenate([x, child], axis=0)
+            allf = jnp.concatenate([fit, cfit], axis=0)
+            keep = jnp.argsort(allf)[:pop]
+            x, fit = allx[keep], allf[keep]
 
-        # 1/5th success rule on the offspring
-        succ = jnp.mean((cfit < jnp.median(fit)).astype(jnp.float32))
-        sigma = jnp.clip(sigma * jnp.where(succ > 0.2, 1.05, 0.95),
-                         1e-8 * (hi - lo), (hi - lo))
-        i = jnp.argmin(fit)
-        better = fit[i] < state["best_val"]
-        return {
-            "pop": x, "fit": fit, "sigma": sigma,
-            "best_val": jnp.where(better, fit[i], state["best_val"]),
-            "best_arg": jnp.where(better, x[i], state["best_arg"]),
-        }
+            # 1/5th success rule on the offspring
+            succ = jnp.mean((cfit < jnp.median(fit)).astype(jnp.float32))
+            sigma = jnp.clip(sigma * jnp.where(succ > 0.2, 1.05, 0.95),
+                             1e-8 * (hi - lo), (hi - lo))
+            i = jnp.argmin(fit)
+            better = fit[i] < state["best_val"]
+            return {
+                "pop": x, "fit": fit, "sigma": sigma,
+                "best_val": jnp.where(better, fit[i], state["best_val"]),
+                "best_arg": jnp.where(better, x[i], state["best_arg"]),
+            }
 
     return MetaHeuristic("ea", init, gen, evals_per_gen=lam, init_evals=pop)

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import obs
 from repro.core.mesh import shard_map as _shard_map
 from repro.functions.benchmarks import Function
 from repro.kernels import registry as kreg
@@ -135,15 +136,17 @@ def make_batch_evaluator(
     _eval_once = _make_eval_once(f, cfg)
 
     def evaluate(pop: Array) -> Array:
-        fit = _eval_once(pop)
+        with obs.scope(obs.EVALUATE):
+            fit = _eval_once(pop)
         if cfg.retry_bad:
-            bad = ~jnp.isfinite(fit)
-            # Retry the failed "batch" once on a perturbed argument (the SPMD
-            # analogue of handing the task to another worker).
-            retried = _eval_once(pop + cfg.retry_eps)
-            fit = jnp.where(bad, retried, fit)
-            # Second failure -> evict from the candidate pool.
-            fit = jnp.where(jnp.isfinite(fit), fit, jnp.inf)
+            with obs.scope(obs.RETRY):
+                bad = ~jnp.isfinite(fit)
+                # Retry the failed "batch" once on a perturbed argument (the
+                # SPMD analogue of handing the task to another worker).
+                retried = _eval_once(pop + cfg.retry_eps)
+                fit = jnp.where(bad, retried, fit)
+                # Second failure -> evict from the candidate pool.
+                fit = jnp.where(jnp.isfinite(fit), fit, jnp.inf)
         return fit
 
     if mesh is None or cfg.mesh_axis is None:

@@ -20,6 +20,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.islands import MetaHeuristic, State, clip_box, uniform_init
 from repro.functions.benchmarks import Function
 
@@ -51,20 +52,22 @@ def make(
 
     def gen(state: State, key: Array) -> State:
         x, fit, alpha = state["pop"], state["fit"], state["alpha"]
-        diff = x[None, :, :] - x[:, None, :]            # (i, j, D): x_j - x_i
-        r2 = jnp.sum(diff * diff, axis=-1)              # (i, j)
-        brighter = (fit[None, :] < fit[:, None]).astype(x.dtype)
-        attract = beta0 * jnp.exp(-gamma * r2) * brighter
-        move = jnp.einsum("ij,ijd->id", attract, diff)
-        noise = alpha * L * (jax.random.uniform(key, x.shape) - 0.5)
-        x = clip_box(x + move + noise, lo, hi)
+        with obs.scope(obs.VARIATION):
+            diff = x[None, :, :] - x[:, None, :]        # (i, j, D): x_j - x_i
+            r2 = jnp.sum(diff * diff, axis=-1)          # (i, j)
+            brighter = (fit[None, :] < fit[:, None]).astype(x.dtype)
+            attract = beta0 * jnp.exp(-gamma * r2) * brighter
+            move = jnp.einsum("ij,ijd->id", attract, diff)
+            noise = alpha * L * (jax.random.uniform(key, x.shape) - 0.5)
+            x = clip_box(x + move + noise, lo, hi)
         fit = evaluator(x)   # the generation's ONLY objective queries: P rows
-        i = jnp.argmin(fit)
-        better = fit[i] < state["best_val"]
-        return {
-            "pop": x, "fit": fit, "alpha": alpha * delta,
-            "best_val": jnp.where(better, fit[i], state["best_val"]),
-            "best_arg": jnp.where(better, x[i], state["best_arg"]),
-        }
+        with obs.scope(obs.SELECT):
+            i = jnp.argmin(fit)
+            better = fit[i] < state["best_val"]
+            return {
+                "pop": x, "fit": fit, "alpha": alpha * delta,
+                "best_val": jnp.where(better, fit[i], state["best_val"]),
+                "best_arg": jnp.where(better, x[i], state["best_arg"]),
+            }
 
     return MetaHeuristic("fa", init, gen, evals_per_gen=pop, init_evals=pop)

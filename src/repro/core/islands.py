@@ -57,6 +57,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import mesh as mesh_mod
 from repro.core import migration as mig
+from repro.core import obs
 from repro.core.api import OptimizeResult
 from repro.core.executor import ExecutorConfig, make_batch_evaluator
 from repro.core.mesh import MeshConfig
@@ -343,6 +344,10 @@ class IslandOptimizer:
             return br
 
         def round_fn(state: State, key: Array) -> State:
+            with obs.scope(obs.ROUND):
+                return round_body(state, key)
+
+        def round_body(state: State, key: Array) -> State:
             br = _local_branch()
 
             def one_gen(carry: State, k: Array) -> tuple[State, None]:
@@ -377,28 +382,31 @@ class IslandOptimizer:
                         oa = _local_rows(oa, axis, n_local)
                     mig_alive = jnp.where(oa[:, None], state["alive"],
                                           jnp.isfinite(state["fit"]))
-                pop, fit = mig.migrate(
-                    cfg.migration, state["pop"], state["fit"],
-                    k=cfg.n_migrants, alive=mig_alive,
-                    axis=axis, n_shards=n_shards,
-                )
-                state = {**state, "pop": pop, "fit": fit}
-                if port is not None or pf.has_adopt_state(algo.name):
-                    # Migration carries pos/fit only; slots whose values
-                    # changed hold adopted migrants. They revive (alive) and
-                    # the destination policy re-initializes its aux slots
-                    # (velocity, pbest, age, ... — DESIGN.md §10). The plain
-                    # engine applies the same registered adopt rules to the
-                    # native state, so homogeneous portfolios stay
-                    # bit-identical to it for EVERY policy — and plain ga/pso
-                    # no longer re-kill or mislead the migrants they adopt.
-                    adopted = (jnp.any(pop != old_pop, axis=-1)
-                               | (fit != old_fit))
-                    if port is not None:
-                        state = port.adopt_stacked(state, adopted, br)
-                    else:
-                        state = jax.vmap(partial(pf.adopt_native, algo.name))(
-                            state, adopted)
+                with obs.scope(obs.MIGRATE):
+                    pop, fit = mig.migrate(
+                        cfg.migration, state["pop"], state["fit"],
+                        k=cfg.n_migrants, alive=mig_alive,
+                        axis=axis, n_shards=n_shards,
+                    )
+                    state = {**state, "pop": pop, "fit": fit}
+                    if port is not None or pf.has_adopt_state(algo.name):
+                        # Migration carries pos/fit only; slots whose values
+                        # changed hold adopted migrants. They revive (alive)
+                        # and the destination policy re-initializes its aux
+                        # slots (velocity, pbest, age, ... — DESIGN.md §10).
+                        # The plain engine applies the same registered adopt
+                        # rules to the native state, so homogeneous
+                        # portfolios stay bit-identical to it for EVERY
+                        # policy — and plain ga/pso no longer re-kill or
+                        # mislead the migrants they adopt.
+                        adopted = (jnp.any(pop != old_pop, axis=-1)
+                                   | (fit != old_fit))
+                        if port is not None:
+                            state = port.adopt_stacked(state, adopted, br)
+                        else:
+                            state = jax.vmap(
+                                partial(pf.adopt_native, algo.name))(
+                                    state, adopted)
 
             if stacked and cfg.share_incumbent:
                 bv, ba = state["best_val"], state["best_arg"]
@@ -451,6 +459,11 @@ class IslandOptimizer:
 
         def round_fn(state: State, rk: Array, step_g: Array,
                      deliver_g: Array) -> State:
+            with obs.scope(obs.ROUND):
+                return round_body(state, rk, step_g, deliver_g)
+
+        def round_body(state: State, rk: Array, step_g: Array,
+                       deliver_g: Array) -> State:
             br = None
             if port is not None and port.n_branches > 1:
                 br = local(jnp.asarray(port.branch_of))
@@ -482,23 +495,25 @@ class IslandOptimizer:
                 policy, old_policy)
 
             if cfg.migration == "ring":
-                old_pop, old_fit = policy["pop"], policy["fit"]
-                box = mig.mailbox_post(
-                    box, old_pop, old_fit, cfg.n_migrants,
-                    step_row & deliver_row, axis=axis, n_shards=n_shards)
-                pop, fit, box = mig.mailbox_adopt(
-                    box, old_pop, old_fit, cfg.max_staleness, step_row)
-                policy = {**policy, "pop": pop, "fit": fit}
-                if port is not None or pf.has_adopt_state(algo.name):
-                    # Same adopted-slot detection + aux re-init as the
-                    # barrier round body (DESIGN.md §10).
-                    adopted = (jnp.any(pop != old_pop, axis=-1)
-                               | (fit != old_fit))
-                    if port is not None:
-                        policy = port.adopt_stacked(policy, adopted, br)
-                    else:
-                        policy = jax.vmap(partial(pf.adopt_native, algo.name))(
-                            policy, adopted)
+                with obs.scope(obs.MIGRATE):
+                    old_pop, old_fit = policy["pop"], policy["fit"]
+                    box = mig.mailbox_post(
+                        box, old_pop, old_fit, cfg.n_migrants,
+                        step_row & deliver_row, axis=axis, n_shards=n_shards)
+                    pop, fit, box = mig.mailbox_adopt(
+                        box, old_pop, old_fit, cfg.max_staleness, step_row)
+                    policy = {**policy, "pop": pop, "fit": fit}
+                    if port is not None or pf.has_adopt_state(algo.name):
+                        # Same adopted-slot detection + aux re-init as the
+                        # barrier round body (DESIGN.md §10).
+                        adopted = (jnp.any(pop != old_pop, axis=-1)
+                                   | (fit != old_fit))
+                        if port is not None:
+                            policy = port.adopt_stacked(policy, adopted, br)
+                        else:
+                            policy = jax.vmap(
+                                partial(pf.adopt_native, algo.name))(
+                                    policy, adopted)
 
             if cfg.share_incumbent:
                 bv, ba = policy["best_val"], policy["best_arg"]
@@ -551,14 +566,15 @@ class IslandOptimizer:
         k = min(cfg.polish_topk, cfg.pop)
 
         def polish_island(state: State) -> State:
-            pop, fit = state["pop"], state["fit"]
-            _, idx = jax.lax.top_k(-fit, k)        # k best (smallest) fitness
-            xs, fs = pop[idx], fit[idx]
-            xs2, fs2 = polish(xs, fs)
-            better = fs2 < fs                      # polish is monotone; guard anyway
-            pop = pop.at[idx].set(jnp.where(better[:, None], xs2, xs))
-            fit = fit.at[idx].set(jnp.where(better, fs2, fs))
-            return track_best(state, pop, fit)
+            with obs.scope(obs.POLISH):
+                pop, fit = state["pop"], state["fit"]
+                _, idx = jax.lax.top_k(-fit, k)    # k best (smallest) fitness
+                xs, fs = pop[idx], fit[idx]
+                xs2, fs2 = polish(xs, fs)
+                better = fs2 < fs                  # polish is monotone; guard anyway
+                pop = pop.at[idx].set(jnp.where(better[:, None], xs2, xs))
+                fit = fit.at[idx].set(jnp.where(better, fs2, fs))
+                return track_best(state, pop, fit)
 
         pass_fn = jax.vmap(polish_island) if cfg.n_islands > 1 else polish_island
         return pass_fn, descent.polish_evals_per_point(cfg.dim, pcfg)
@@ -840,25 +856,31 @@ class IslandOptimizer:
         n_rounds, per_round, n_polish, per_polish = self._budget(
             per_gen_total, init_total, pp)
 
-        key, ik = jax.random.split(key)
-        state = self._init_state(algo, ik)
-        if warm is not None and len(warm):
-            state = self._inject_warm(f, algo, state, warm)
-        state = self._shard_state(state)
-        round_keys = _chain_split(key, n_rounds)
-        if self._async:
-            step_m, deliver_m = self._materialize_schedule(n_rounds)
+        with obs.span(obs.ENGINE_INIT):
+            key, ik = jax.random.split(key)
+            state = self._init_state(algo, ik)
+            if warm is not None and len(warm):
+                state = self._inject_warm(f, algo, state, warm)
+            state = self._shard_state(state)
+            round_keys = _chain_split(key, n_rounds)
+            if self._async:
+                step_m, deliver_m = self._materialize_schedule(n_rounds)
 
         ctx = self.mesh if self.mesh is not None else _nullcontext()
         with ctx:
             if self.round_callback is None:
                 # Device-resident path: one jit, one host pull at the end.
                 if self._async:
-                    arg, val, history, stale = jax.device_get(
-                        run(state, round_keys, step_m, deliver_m))
+                    with obs.span(obs.ENGINE_DISPATCH):
+                        out = run(state, round_keys, step_m, deliver_m)
+                    with obs.span(obs.ENGINE_FETCH):
+                        arg, val, history, stale = jax.device_get(out)
                     self.last_max_staleness = int(stale)
                 else:
-                    arg, val, history = jax.device_get(run(state, round_keys))
+                    with obs.span(obs.ENGINE_DISPATCH):
+                        out = run(state, round_keys)
+                    with obs.span(obs.ENGINE_FETCH):
+                        arg, val, history = jax.device_get(out)
             else:
                 # Host-stepped path: round granularity for checkpoint/coupling.
                 # Polish applies on the same cadence, BEFORE the history/
@@ -1073,11 +1095,16 @@ class IslandOptimizer:
         with ctx:
             if self._async:
                 step_m, deliver_m = self._materialize_schedule(n_rounds)
-                args, vals, hists, stale = jax.device_get(
-                    many(keys, step_m, deliver_m))
+                with obs.span(obs.ENGINE_DISPATCH):
+                    out = many(keys, step_m, deliver_m)
+                with obs.span(obs.ENGINE_FETCH):
+                    args, vals, hists, stale = jax.device_get(out)
                 self.last_max_staleness = int(np.max(stale))
             else:
-                args, vals, hists = jax.device_get(many(keys))
+                with obs.span(obs.ENGINE_DISPATCH):
+                    out = many(keys)
+                with obs.span(obs.ENGINE_FETCH):
+                    args, vals, hists = jax.device_get(out)
 
         n_evals = (init_total + n_rounds * per_round + n_polish * per_polish)
         return [
@@ -1220,7 +1247,8 @@ class BucketStepper:
         callers must not reuse the argument after the call."""
         fn = (self._step_polish
               if self.has_polish and (r + 1) % self.every == 0 else self._step)
-        return fn(state, round_keys[:, r])
+        with obs.span(obs.ENGINE_STEP):
+            return fn(state, round_keys[:, r])
 
     def best(self, state: State) -> tuple[Array, Array]:
         """Per-job global incumbent ``(args (J, dim), vals (J,))`` from the

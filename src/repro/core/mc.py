@@ -9,6 +9,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.islands import MetaHeuristic, State, uniform_init
 from repro.functions.benchmarks import Function
 
@@ -31,14 +32,16 @@ def make(
         return {"pop": x, "fit": fit, "best_arg": x[i], "best_val": fit[i]}
 
     def gen(state: State, key: Array) -> State:
-        x = uniform_init(key, pop, dim, lo, hi)
+        with obs.scope(obs.VARIATION):
+            x = uniform_init(key, pop, dim, lo, hi)
         fit = evaluator(x)
-        i = jnp.argmin(fit)
-        better = fit[i] < state["best_val"]
-        return {
-            "pop": x, "fit": fit,
-            "best_val": jnp.where(better, fit[i], state["best_val"]),
-            "best_arg": jnp.where(better, x[i], state["best_arg"]),
-        }
+        with obs.scope(obs.SELECT):
+            i = jnp.argmin(fit)
+            better = fit[i] < state["best_val"]
+            return {
+                "pop": x, "fit": fit,
+                "best_val": jnp.where(better, fit[i], state["best_val"]),
+                "best_arg": jnp.where(better, x[i], state["best_arg"]),
+            }
 
     return MetaHeuristic("mc", init, gen, evals_per_gen=pop, init_evals=pop)

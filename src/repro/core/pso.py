@@ -18,6 +18,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.islands import MetaHeuristic, State, clip_box, uniform_init
 from repro.functions.benchmarks import Function
 from repro.kernels import registry as kreg
@@ -59,24 +60,28 @@ def make(
 
     def gen(state: State, key: Array) -> State:
         x, v = state["pop"], state["vel"]
-        k1, k2 = jax.random.split(key)
-        r1 = jax.random.uniform(k1, x.shape)
-        r2 = jax.random.uniform(k2, x.shape)
-        v = w * v + fp * r1 * (state["pbest"] - x) + fg * r2 * (state["best_arg"] - x)
-        v = jnp.clip(v, -vmax, vmax)
-        x = clip_box(x + v, lo, hi)
+        with obs.scope(obs.VARIATION):
+            k1, k2 = jax.random.split(key)
+            r1 = jax.random.uniform(k1, x.shape)
+            r2 = jax.random.uniform(k2, x.shape)
+            v = (w * v + fp * r1 * (state["pbest"] - x)
+                 + fg * r2 * (state["best_arg"] - x))
+            v = jnp.clip(v, -vmax, vmax)
+            x = clip_box(x + v, lo, hi)
         fit = evaluator(x)
 
-        imp = fit < state["pbest_f"]
-        pbest = jnp.where(imp[:, None], x, state["pbest"])
-        pbest_f = jnp.where(imp, fit, state["pbest_f"])
-        i = jnp.argmin(pbest_f)
-        better = pbest_f[i] < state["best_val"]
-        return {
-            "pop": x, "fit": fit, "vel": v, "pbest": pbest, "pbest_f": pbest_f,
-            "best_val": jnp.where(better, pbest_f[i], state["best_val"]),
-            "best_arg": jnp.where(better, pbest[i], state["best_arg"]),
-        }
+        with obs.scope(obs.SELECT):
+            imp = fit < state["pbest_f"]
+            pbest = jnp.where(imp[:, None], x, state["pbest"])
+            pbest_f = jnp.where(imp, fit, state["pbest_f"])
+            i = jnp.argmin(pbest_f)
+            better = pbest_f[i] < state["best_val"]
+            return {
+                "pop": x, "fit": fit, "vel": v, "pbest": pbest,
+                "pbest_f": pbest_f,
+                "best_val": jnp.where(better, pbest_f[i], state["best_val"]),
+                "best_arg": jnp.where(better, pbest[i], state["best_arg"]),
+            }
 
     step_override = None
     if fused:
@@ -86,22 +91,27 @@ def make(
         def gen_fused(state: State, key: Array) -> State:
             # Same key discipline as gen, so fused and XLA paths draw
             # identical r1/r2 on a fixed seed.
-            k1, k2 = jax.random.split(key)
-            r1 = jax.random.uniform(k1, (pop, dim))
-            r2 = jax.random.uniform(k2, (pop, dim))
-            nx, nv, fit, npb, npbf = _pso_step_kernel(
-                state["pop"], state["vel"], state["pbest"], state["pbest_f"],
-                r1, r2, state["best_arg"], fn=spec.eval_tag, shift=f.shift,
-                bias=f.bias, w=w, fp=fp, fg=fg, vmax=vmax, lo=lo, hi=hi,
-                interpret=interpret, kernel_cfg=kernel_cfg,
-            )
-            i = jnp.argmin(npbf)
-            better = npbf[i] < state["best_val"]
-            return {
-                "pop": nx, "fit": fit, "vel": nv, "pbest": npb, "pbest_f": npbf,
-                "best_val": jnp.where(better, npbf[i], state["best_val"]),
-                "best_arg": jnp.where(better, npb[i], state["best_arg"]),
-            }
+            with obs.scope(obs.VARIATION):
+                k1, k2 = jax.random.split(key)
+                r1 = jax.random.uniform(k1, (pop, dim))
+                r2 = jax.random.uniform(k2, (pop, dim))
+            with obs.scope(obs.FUSED):
+                nx, nv, fit, npb, npbf = _pso_step_kernel(
+                    state["pop"], state["vel"], state["pbest"],
+                    state["pbest_f"], r1, r2, state["best_arg"],
+                    fn=spec.eval_tag, shift=f.shift, bias=f.bias, w=w, fp=fp,
+                    fg=fg, vmax=vmax, lo=lo, hi=hi, interpret=interpret,
+                    kernel_cfg=kernel_cfg,
+                )
+            with obs.scope(obs.SELECT):
+                i = jnp.argmin(npbf)
+                better = npbf[i] < state["best_val"]
+                return {
+                    "pop": nx, "fit": fit, "vel": nv, "pbest": npb,
+                    "pbest_f": npbf,
+                    "best_val": jnp.where(better, npbf[i], state["best_val"]),
+                    "best_arg": jnp.where(better, npb[i], state["best_arg"]),
+                }
 
         step_override = gen_fused
 

@@ -19,6 +19,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.islands import MetaHeuristic, State, clip_box, uniform_init
 from repro.functions.benchmarks import Function
 from repro.kernels import registry as kreg
@@ -64,22 +65,24 @@ def make(
 
     def gen(state: State, key: Array) -> State:
         x, fx, t = state["pop"], state["fit"], state["t"]
-        kp, ka = jax.random.split(key)
-        T = sched(t, T0, float(n_gens_hint))
-        y = clip_box(x + sigma * jax.random.normal(kp, x.shape), lo, hi)
+        with obs.scope(obs.VARIATION):
+            kp, ka = jax.random.split(key)
+            T = sched(t, T0, float(n_gens_hint))
+            y = clip_box(x + sigma * jax.random.normal(kp, x.shape), lo, hi)
         fy = evaluator(y)
-        dF = fy - fx
-        u = jax.random.uniform(ka, fx.shape)
-        accept = (dF <= 0) | (u < jnp.exp(-dF / jnp.maximum(T, 1e-12)))
-        x = jnp.where(accept[:, None], y, x)
-        fx = jnp.where(accept, fy, fx)
-        i = jnp.argmin(fx)
-        better = fx[i] < state["best_val"]
-        return {
-            "pop": x, "fit": fx, "t": t + 1.0,
-            "best_val": jnp.where(better, fx[i], state["best_val"]),
-            "best_arg": jnp.where(better, x[i], state["best_arg"]),
-        }
+        with obs.scope(obs.SELECT):
+            dF = fy - fx
+            u = jax.random.uniform(ka, fx.shape)
+            accept = (dF <= 0) | (u < jnp.exp(-dF / jnp.maximum(T, 1e-12)))
+            x = jnp.where(accept[:, None], y, x)
+            fx = jnp.where(accept, fy, fx)
+            i = jnp.argmin(fx)
+            better = fx[i] < state["best_val"]
+            return {
+                "pop": x, "fit": fx, "t": t + 1.0,
+                "best_val": jnp.where(better, fx[i], state["best_val"]),
+                "best_arg": jnp.where(better, x[i], state["best_arg"]),
+            }
 
     step_override = None
     if fused:
@@ -88,23 +91,26 @@ def make(
 
         def gen_fused(state: State, key: Array) -> State:
             x, fx, t = state["pop"], state["fit"], state["t"]
-            kp, ka = jax.random.split(key)
-            T = sched(t, T0, float(n_gens_hint))
-            y = clip_box(x + sigma * jax.random.normal(kp, x.shape), lo, hi)
-            u = jax.random.uniform(ka, fx.shape)
-            # Metropolis as a threshold: u < exp(-dF/T)  <=>  dF < -T*ln(u)
-            thresh = -jnp.maximum(T, 1e-12) * jnp.log(u)
-            x, fx, _ = _eval_select_kernel(
-                x, fx, y, thresh, fn=spec.eval_tag, shift=f.shift,
-                bias=f.bias, interpret=interpret, kernel_cfg=kernel_cfg,
-            )
-            i = jnp.argmin(fx)
-            better = fx[i] < state["best_val"]
-            return {
-                "pop": x, "fit": fx, "t": t + 1.0,
-                "best_val": jnp.where(better, fx[i], state["best_val"]),
-                "best_arg": jnp.where(better, x[i], state["best_arg"]),
-            }
+            with obs.scope(obs.VARIATION):
+                kp, ka = jax.random.split(key)
+                T = sched(t, T0, float(n_gens_hint))
+                y = clip_box(x + sigma * jax.random.normal(kp, x.shape), lo, hi)
+                u = jax.random.uniform(ka, fx.shape)
+                # Metropolis as a threshold: u < exp(-dF/T) <=> dF < -T*ln(u)
+                thresh = -jnp.maximum(T, 1e-12) * jnp.log(u)
+            with obs.scope(obs.FUSED):
+                x, fx, _ = _eval_select_kernel(
+                    x, fx, y, thresh, fn=spec.eval_tag, shift=f.shift,
+                    bias=f.bias, interpret=interpret, kernel_cfg=kernel_cfg,
+                )
+            with obs.scope(obs.SELECT):
+                i = jnp.argmin(fx)
+                better = fx[i] < state["best_val"]
+                return {
+                    "pop": x, "fit": fx, "t": t + 1.0,
+                    "best_val": jnp.where(better, fx[i], state["best_val"]),
+                    "best_arg": jnp.where(better, x[i], state["best_arg"]),
+                }
 
         step_override = gen_fused
 

@@ -63,6 +63,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.checkpoint.store import CheckpointStore
+from repro.core import obs
 from repro.core.api import OptimizeResult, OptRequest, OptResponse
 from repro.core.executor import ExecutorConfig
 from repro.core.islands import IslandConfig, IslandOptimizer
@@ -374,39 +375,40 @@ class ShapeBucketScheduler:
 
     def _run_bucket(self, item: _RunItem) -> None:
         """Execute one dispatched bucket (worker-thread or inline body)."""
-        key, rows = item.key, item.rows
-        with self._mu:
-            # cancellations that arrived while queued: finalize without running
-            for j in list(rows):
-                if j is not None and j.cancel_requested and not j.finished():
-                    self._finalize(j, "cancelled")
-            live = [j for j in rows
-                    if j is not None and not j.finished()]
-            if not live:
-                return
-            for j in live:
-                j.response.status = "running"
-        req0 = live[0].request
-        try:
-            opt = self._optimizer(req0)
-            f = self._function(req0)
-            try:
-                stepper = opt.bucket_stepper(f)
-            except ValueError:      # sharded/meshed engine: no host stepping
-                stepper = None
-            if stepper is None:
-                self._run_resident(item, opt, f)
-            else:
-                self._run_stepped(item, stepper)
-        except AbandonRun:
-            raise
-        except Exception as e:  # noqa: BLE001 — job-level fault isolation
-            msg = f"{type(e).__name__}: {e}"
-            traceback.print_exc()
+        with obs.span(obs.SCHED_RUN):
+            key, rows = item.key, item.rows
             with self._mu:
-                for j in rows:
-                    if j is not None and not j.finished():
-                        self._finalize(j, "error", error=msg)
+                # cancellations that arrived while queued: finalize without running
+                for j in list(rows):
+                    if j is not None and j.cancel_requested and not j.finished():
+                        self._finalize(j, "cancelled")
+                live = [j for j in rows
+                        if j is not None and not j.finished()]
+                if not live:
+                    return
+                for j in live:
+                    j.response.status = "running"
+            req0 = live[0].request
+            try:
+                opt = self._optimizer(req0)
+                f = self._function(req0)
+                try:
+                    stepper = opt.bucket_stepper(f)
+                except ValueError:      # sharded/meshed engine: no host stepping
+                    stepper = None
+                if stepper is None:
+                    self._run_resident(item, opt, f)
+                else:
+                    self._run_stepped(item, stepper)
+            except AbandonRun:
+                raise
+            except Exception as e:  # noqa: BLE001 — job-level fault isolation
+                msg = f"{type(e).__name__}: {e}"
+                traceback.print_exc()
+                with self._mu:
+                    for j in rows:
+                        if j is not None and not j.finished():
+                            self._finalize(j, "error", error=msg)
 
     def _run_resident(self, item: _RunItem, opt: IslandOptimizer, f) -> None:
         """Device-resident fallback (sharded/meshed buckets): one opaque
@@ -490,7 +492,8 @@ class ShapeBucketScheduler:
 
         for r in range(start, n_rounds):
             state, vals = stepper.step(state, round_keys, r)
-            vals_np = np.asarray(vals)
+            with obs.span(obs.SCHED_PROGRESS):
+                vals_np = np.asarray(vals)
             hist.append(vals_np)
             r_done = r + 1
             for i in live:

@@ -1,5 +1,6 @@
 """Compile the five fused kernels at Table I width (800x1000) for a described
-TPU v5e chip, with the tile the autotuner picks for the TPU.
+TPU v5e chip, with the tile the autotuner picks for the TPU; and Table I's
+whole-run program, whose phase scopes must change no op.
 
 No chip is needed: the TPU compiler runs here against a described topology
 and raises what Mosaic would raise on the chip (tiling, layouts, VMEM, and
@@ -10,13 +11,19 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU's library, and every test worker imports
 this file.
 """
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import obs
+from repro.core.api import OptRequest
+from repro.core.scheduler import build_optimizer
+from repro.functions import get
 from repro.kernels import autotune
 from repro.kernels.bench_eval import bench_eval
 from repro.kernels.de_step import de_step
@@ -84,6 +91,53 @@ def test_kernel_compiles_for_v5e(kind, tag, one_chip):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
     text = jax.jit(call).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, (kind, tag, kc)
+
+
+TABLE1 = OptRequest(fn="shifted_rosenbrock", algo="de", backend="xla", dim=D,
+                    pop=P, n_islands=1, migration="none", sync_every=10,
+                    max_evals=P * 20_001,
+                    params=(("w", 0.5), ("px", 0.2), ("strategy", "rand1bin"),
+                            ("barrier_mode", "chunked")))
+
+
+def _table1_text(one_chip) -> str:
+    """Table I's whole-run program (``minimize``'s one dispatch) compiled
+    for the described chip."""
+    opt, f = build_optimizer(TABLE1), get(TABLE1.fn, TABLE1.dim)
+    algo, run, pp = opt._single_fn(f)
+    n_rounds = opt._budget(*opt._eval_totals(algo), pp)[0]
+    state = jax.eval_shape(algo.init, jax.random.PRNGKey(0))
+    args = (jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), state),
+            jax.ShapeDtypeStruct((n_rounds, 2), jnp.uint32, sharding=one_chip))
+    return run.lower(*args).compile().as_text()
+
+
+def _ops(text: str) -> list[str]:
+    return [re.sub(r", metadata=\{[^}]*\}", "", line)
+            for line in text.splitlines()
+            if re.match(r"\s*(ROOT )?%\S+ = ", line)
+            or re.match(r"\s*(ENTRY )?%\S+ .*\{\s*$", line)]
+
+
+def test_table1_program_names_its_phases_and_changes_no_op(one_chip,
+                                                           monkeypatch):
+    """On the chip's compiler Table I's program carries every phase scope,
+    holds exactly one ``while`` directly under ``popt.round`` (the scan over
+    a round's generations, which counts generations in a trace), and is the
+    same program op for op as with every scope a no-op."""
+    text = _table1_text(one_chip)
+    names = re.findall(r'op_name="([^"]*)"', text)
+    scopes = {s for n in names for s in re.findall(r"popt\.[a-z_.]+", n)}
+    assert {obs.ROUND, obs.VARIATION, obs.EVALUATE, obs.RETRY,
+            obs.SELECT} <= scopes
+    loops = [n for line in text.splitlines()
+             if re.match(r"\s*(ROOT )?%while\S* = ", line)
+             for n in re.findall(r'op_name="([^"]*)"', line)
+             if n.endswith(obs.ROUND + "/while")]
+    assert len(loops) == 1, loops
+    monkeypatch.setattr(obs, "scope", lambda name: contextlib.nullcontext())
+    assert _ops(_table1_text(one_chip)) == _ops(text)
 
 
 def test_tile_scores_use_the_attached_tpu_row(monkeypatch):
