@@ -23,10 +23,12 @@ exercised on CPU.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.extend.random import threefry2x32_p
 
 from repro.core import obs
 from repro.core.islands import MetaHeuristic, State, clip_box, track_best, uniform_init
@@ -60,6 +62,58 @@ def _trials(pop: Array, best: Array, key: Array, w: float, px: float,
     jrand = jax.random.randint(kj, (P,), 0, D)
     cross = cross | (jnp.arange(D)[None, :] == jrand[:, None])
     return jnp.where(cross, mutant, pop)
+
+
+def _uniform_rows(key: Array, P: int, D: int, start: Array, n: int) -> Array:
+    """Rows ``[start, start + n)`` of ``jax.random.uniform(key, (P, D))``, bit
+    for bit, drawing only those rows.
+
+    Partitionable threefry (JAX's default) makes element ``i`` of a draw the
+    hash of ``key`` and its flat index ``i``: this is
+    ``jax._src.prng._threefry_random_bits_partitionable`` followed by
+    ``uniform``'s float32 mantissa trick, on the block's counters alone (XLA
+    does not push a slice of a full draw into the hash). Any other setting
+    (non-partitionable threefry, another PRNG implementation, 64-bit floats,
+    a draw of 2**32 or more counters) takes the full draw and slices it.
+    """
+    typed = jax.dtypes.issubdtype(key.dtype, jax.dtypes.prng_key)
+    impl = (str(jax.random.key_impl(key)) if typed
+            else jax.config.jax_default_prng_impl)
+    if not (impl == "threefry2x32" and jax.config.jax_threefry_partitionable
+            and not jax.config.jax_enable_x64 and P * D < 2**32):
+        return jax.lax.dynamic_slice_in_dim(
+            jax.random.uniform(key, (P, D)), start, n, 0)
+    return _threefry_uniform_rows(jax.random.key_data(key) if typed else key,
+                                  start, n, D)
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _threefry_uniform_rows(k: Array, start: Array, n: int, D: int) -> Array:
+    # Its own jit, as jax.random.uniform is, so the ops' names do not take
+    # the caller's phase scope.
+    lo = (jnp.asarray(start, jnp.uint32) * jnp.uint32(D)
+          + jax.lax.iota(jnp.uint32, n * D)).reshape(n, D)
+    b1, b2 = threefry2x32_p.bind(k[0], k[1], jnp.zeros_like(lo), lo)
+    bits = ((b1 ^ b2) >> 9) | jnp.uint32(0x3F800000)
+    return jnp.maximum(0.0, jax.lax.bitcast_convert_type(bits, jnp.float32) - 1.0)
+
+
+def _chunk_trials(pop: Array, best: Array, key: Array, start: Array, n: int,
+                  w: float, px: float, strategy: str) -> Array:
+    """Rows ``[start, start + n)`` of ``_trials(pop, best, key, ...)``, bit for
+    bit: the donors and forced coordinates are drawn for all P rows and
+    sliced (P scalars each), the crossover uniforms only for the block."""
+    P, D = pop.shape
+    ksel, kcr, kj = jax.random.split(key, 3)
+    ra, rb, rc = (jax.lax.dynamic_slice_in_dim(r, start, n)
+                  for r in _distinct3(ksel, P))
+    base = pop[ra] if strategy == "rand1bin" else jnp.broadcast_to(best, (n, D))
+    mutant = base + w * (pop[rb] - pop[rc])
+    cross = _uniform_rows(kcr, P, D, start, n) < px
+    jrand = jax.lax.dynamic_slice_in_dim(
+        jax.random.randint(kj, (P,), 0, D), start, n)
+    cross = cross | (jnp.arange(D)[None, :] == jrand[:, None])
+    return jnp.where(cross, mutant, jax.lax.dynamic_slice_in_dim(pop, start, n, 0))
 
 
 def make(
@@ -108,10 +162,11 @@ def make(
             p, fit = carry
             with obs.scope(obs.VARIATION):
                 ck = jax.random.fold_in(key, c)
-                start = c * csz
-                trial_all = clip_box(
-                    _trials(p, p[jnp.argmin(fit)], ck, w, px, strategy), lo, hi)
-                trial = jax.lax.dynamic_slice_in_dim(trial_all, start, csz, 0)
+                # The last chunk overlaps its predecessor when csz does not
+                # divide pop; the draw's rows follow the same clamped start.
+                start = jnp.minimum(c * csz, pop - csz)
+                trial = clip_box(_chunk_trials(p, p[jnp.argmin(fit)], ck, start,
+                                               csz, w, px, strategy), lo, hi)
             with obs.scope(obs.SELECT):
                 cur_f = jax.lax.dynamic_slice_in_dim(fit, start, csz, 0)
                 cur_p = jax.lax.dynamic_slice_in_dim(p, start, csz, 0)

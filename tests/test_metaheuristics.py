@@ -213,3 +213,94 @@ def test_evals_per_gen_parity(name, params):
     jax.block_until_ready(algo.gen(state, jax.random.PRNGKey(1)))
     barrier()
     assert sum(counted) == algo.evals_per_gen, (name, counted)
+
+
+# --- chunked DE draws only its chunk's rows, on the same random stream --------
+
+def _chunked_gen_oracle(f, evaluator, pop, strategy, w=0.5, px=0.2, n_chunks=8):
+    """Chunked DE as it was first written: every chunk builds the whole
+    population's trials with ``_trials`` and keeps its own rows."""
+    from repro.core import de
+    from repro.core.islands import clip_box, track_best
+    csz = max(1, pop // n_chunks)
+
+    def gen(state, key):
+        def body(c, carry):
+            p, fit = carry
+            start = jnp.minimum(c * csz, pop - csz)
+            trial = jax.lax.dynamic_slice_in_dim(clip_box(de._trials(
+                p, p[jnp.argmin(fit)], jax.random.fold_in(key, c), w, px,
+                strategy), f.lo, f.hi), start, csz, 0)
+            cur_f = jax.lax.dynamic_slice_in_dim(fit, start, csz, 0)
+            cur_p = jax.lax.dynamic_slice_in_dim(p, start, csz, 0)
+            tfit = evaluator(trial)
+            better = tfit <= cur_f
+            p = jax.lax.dynamic_update_slice_in_dim(
+                p, jnp.where(better[:, None], trial, cur_p), start, 0)
+            fit = jax.lax.dynamic_update_slice_in_dim(
+                fit, jnp.where(better, tfit, cur_f), start, 0)
+            return p, fit
+
+        p, fit = jax.lax.fori_loop(0, -(-pop // csz), body,
+                                   (state["pop"], state["fit"]))
+        return track_best(state, p, fit)
+
+    return gen
+
+
+@pytest.mark.parametrize("n_islands", [1, 3])
+@pytest.mark.parametrize("strategy", ["rand1bin", "best1bin"])
+@pytest.mark.parametrize("pop", [32, 37])
+def test_de_chunked_is_bit_identical_to_full_population_trials(pop, strategy,
+                                                                n_islands):
+    """Each chunk builds only its own rows' trials, from the rows of the
+    full-population draw it used to make and slice: a fixed seed gives the
+    same generations bit for bit (pop 37 has a clamped, overlapping last
+    chunk; three islands run under ``vmap`` with batched keys)."""
+    from repro.core import de
+    dim = 11
+    f = get("rastrigin")
+    evaluator = jax.vmap(f.fn)
+    algo = de.make(f, evaluator, pop, dim, strategy=strategy,
+                   barrier_mode="chunked")
+    oracle = _chunked_gen_oracle(f, evaluator, pop, strategy)
+    keys = jax.random.split(jax.random.PRNGKey(11), n_islands)
+    new = old = jax.vmap(algo.init)(keys)
+    step_new, step_old = jax.jit(jax.vmap(algo.gen)), jax.jit(jax.vmap(oracle))
+    for g in range(4):
+        gkeys = jax.vmap(lambda k: jax.random.fold_in(k, g))(keys)
+        new, old = step_new(new, gkeys), step_old(old, gkeys)
+        for name in ("pop", "fit", "best_arg", "best_val"):
+            np.testing.assert_array_equal(np.asarray(new[name]),
+                                          np.asarray(old[name]), err_msg=name)
+    assert not np.array_equal(np.asarray(new["pop"]),
+                              np.asarray(jax.vmap(algo.init)(keys)["pop"]))
+
+
+@pytest.mark.parametrize("kind", ["raw", "typed", "rbg"])
+@pytest.mark.parametrize("partitionable", [True, False],
+                         ids=["partitionable", "fallback"])
+def test_uniform_rows_match_the_full_draw(partitionable, kind, monkeypatch):
+    """The row-block draw equals ``uniform(k, (P, D))[s:s+n]`` bit for bit at
+    every start, clamped last block included, for raw and typed threefry
+    keys; with partitionable threefry off, or a key of another PRNG, it
+    takes the full draw and slices it, and still equals it."""
+    from repro.core import de
+    P, D, n = 37, 13, 4
+    key = {"raw": jax.random.PRNGKey(5), "typed": jax.random.key(5),
+           "rbg": jax.random.key(5, impl="rbg")}[kind]
+    uniform, draws = jax.random.uniform, []
+
+    def counted_uniform(k, shape, *a, **kw):
+        draws.append(shape)
+        return uniform(k, shape, *a, **kw)
+
+    with jax.threefry_partitionable(partitionable):
+        full = np.asarray(uniform(key, (P, D)))
+        monkeypatch.setattr(jax.random, "uniform", counted_uniform)
+        rows = jax.jit(de._uniform_rows, static_argnums=(1, 2, 4))
+        for s in (0, 4, 9, P - n):
+            np.testing.assert_array_equal(np.asarray(rows(key, P, D, s, n)),
+                                          full[s:s + n])
+    fast = partitionable and kind != "rbg"
+    assert draws == ([] if fast else [(P, D)]), draws

@@ -1,6 +1,7 @@
 """Compile the five fused kernels at Table I width (800x1000) for a described
 TPU v5e chip, with the tile the autotuner picks for the TPU; and Table I's
-whole-run program, whose phase scopes must change no op.
+whole-run program, whose phase scopes must change no op and whose chunks
+build only their own rows.
 
 No chip is needed: the TPU compiler runs here against a described topology
 and raises what Mosaic would raise on the chip (tiling, layouts, VMEM, and
@@ -138,6 +139,17 @@ def test_table1_program_names_its_phases_and_changes_no_op(one_chip,
     assert len(loops) == 1, loops
     monkeypatch.setattr(obs, "scope", lambda name: contextlib.nullcontext())
     assert _ops(_table1_text(one_chip)) == _ops(text)
+
+
+def test_table1_chunks_build_only_their_own_rows(one_chip):
+    """On the chip's compiler each of Table I's chunks draws random bits and
+    gathers donor rows for its own 100 rows alone: no op of the program holds
+    the whole population's (800, 1000) bits, and every donor gather yields
+    (100, 1000)."""
+    text = _table1_text(one_chip)
+    assert f"u32[{P},{D}]" not in text
+    gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text)
+    assert gathers == [f"f32[{P // 8},{D}]"] * 3, gathers
 
 
 def test_tile_scores_use_the_attached_tpu_row(monkeypatch):
