@@ -413,8 +413,9 @@ class IslandOptimizer:
                 if axis is not None and n_shards > 1:
                     # Device-side Observer across shards: gather every
                     # island's incumbent, broadcast the global best back.
-                    gbv = jax.lax.all_gather(bv, axis, tiled=True)
-                    gba = jax.lax.all_gather(ba, axis, tiled=True)
+                    with obs.scope(obs.SYNC):
+                        gbv = jax.lax.all_gather(bv, axis, tiled=True)
+                        gba = jax.lax.all_gather(ba, axis, tiled=True)
                 else:
                     gbv, gba = bv, ba
                 gi = jnp.argmin(gbv)
@@ -518,8 +519,9 @@ class IslandOptimizer:
             if cfg.share_incumbent:
                 bv, ba = policy["best_val"], policy["best_arg"]
                 if axis is not None and n_shards > 1:
-                    gbv = jax.lax.all_gather(bv, axis, tiled=True)
-                    gba = jax.lax.all_gather(ba, axis, tiled=True)
+                    with obs.scope(obs.SYNC):
+                        gbv = jax.lax.all_gather(bv, axis, tiled=True)
+                        gba = jax.lax.all_gather(ba, axis, tiled=True)
                 else:
                     gbv, gba = bv, ba
                 gi = jnp.argmin(gbv)
@@ -601,7 +603,8 @@ class IslandOptimizer:
                 bv = carry["best_val"]
                 point = jnp.min(bv) if stacked else bv
                 if axis is not None and n_shards > 1:
-                    point = jax.lax.pmin(point, axis)   # exact: min of mins
+                    with obs.scope(obs.SYNC):
+                        point = jax.lax.pmin(point, axis)   # exact: min of mins
                 return carry, point
 
             rs = jnp.arange(round_keys.shape[0])
@@ -630,7 +633,8 @@ class IslandOptimizer:
                         (r + 1) % every == 0, polish_pass, lambda s: s, carry)
                 point = jnp.min(carry["best_val"])
                 if axis is not None and n_shards > 1:
-                    point = jax.lax.pmin(point, axis)
+                    with obs.scope(obs.SYNC):
+                        point = jax.lax.pmin(point, axis)
                 return carry, point
 
             rs = jnp.arange(round_keys.shape[0])

@@ -28,9 +28,11 @@ SELECT = "popt.select"         # replacement and incumbent tracking
 FUSED = "popt.fused"           # one whole-generation Pallas kernel call
 ROUND = "popt.round"           # one sync round: the generation scan + merge
 MIGRATE = "popt.migrate"       # ring / starvation / mailbox exchange
+SYNC = "popt.sync"             # cross-chip incumbent merge: pmin, all-gathers
 POLISH = "popt.polish"         # the memetic local-descent pass
 
-SCOPES = (VARIATION, EVALUATE, RETRY, SELECT, FUSED, ROUND, MIGRATE, POLISH)
+SCOPES = (VARIATION, EVALUATE, RETRY, SELECT, FUSED, ROUND, MIGRATE, SYNC,
+          POLISH)
 
 # -- host spans (calling thread) -------------------------------------------
 ENGINE_INIT = "popt.engine.init"          # init state, warm start, round keys

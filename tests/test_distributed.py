@@ -10,6 +10,11 @@ Two tiers, matching the determinism contract:
   ``XLA_FLAGS=--xla_force_host_platform_device_count=8``; conftest.py
   deliberately does NOT force them for the rest of the suite.
 """
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -168,6 +173,67 @@ def test_scheduler_runs_sharded_bucket():
         assert np.array_equal(np.asarray(got.result.arg),
                               np.asarray(expect.arg))
     assert sched.result(plain_id).status == "done"
+
+
+# --- the served ring on four devices, in a child process (tier-1) ----------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+SERVED_RING4 = """
+import json, sys
+import numpy as np
+from bench import check
+from repro.launch.opt_serve import OptimizationService
+
+cfg = json.load(open("bench/configs/cec2008_f3_islands8_ring.json"))
+req = dict(cfg["request"], dim=40, pop=32, max_evals=8 * 32 * 21)
+out = {}
+for devices in (4, 1):
+    service = OptimizationService(max_batch=1, workers=0)
+    reply = service.handle({"op": "submit",
+                            "request": dict(req, devices=devices, seed=9)})
+    resp = service.scheduler.result(reply["id"], evict=True)
+    assert resp.status == "done", resp.error
+    out[devices] = resp.result
+a, b = out[4], out[1]
+ans = check.Answer(req, 9, "done", a.value, np.asarray(a.arg), a.n_evals,
+                   np.asarray(a.history))
+print(json.dumps({
+    "rounds": len(a.history),
+    "history_same": np.array_equal(a.history, b.history),
+    "arg_same": np.array_equal(np.asarray(a.arg), np.asarray(b.arg)),
+    "value_same": a.value == b.value,
+    "numbers": check.numbers([ans], 9, len(a.history), 1),
+    "limits": cfg["check"]["limits"]}))
+"""
+
+
+def _four_device_child(code: str) -> dict:
+    """Run ``code`` in a child process that sees four CPU devices (this
+    process sees one) and return the JSON of its last line of output."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([os.path.join(ROOT, "src"), ROOT]),
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
+                          + " --xla_force_host_platform_device_count=4"))
+    p = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-4000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+def test_served_ring_on_four_devices_matches_one_device_and_the_reference():
+    """The four-chip cell's request, at pop 32 and dim 40, submitted through
+    ``OptimizationService``: the sharded bucket (two islands per device,
+    ring migration across devices) gives the history and argument of the
+    same request at ``devices=1``, and the plain reference's replay of the
+    whole run agrees within the configuration's limits."""
+    r = _four_device_child(SERVED_RING4)
+    assert r["rounds"] == 2
+    assert r["history_same"] and r["arg_same"] and r["value_same"]
+    nums, limits = r["numbers"], r["limits"]
+    assert nums["unanswered"] == 0 and nums["work_gap"] == 0, nums
+    assert nums["value_gap"] <= limits["value_gap"], nums
+    assert nums["replay_gap"] <= limits["replay_gap"], nums
 
 
 # --- request plumbing and validation (device-count independent) -------------

@@ -4,8 +4,11 @@ engine's and the scheduler's host spans land in a profiler capture, and the
 scopes change no op of the compiled program."""
 import contextlib
 import glob
+import json
 import os
 import re
+import subprocess
+import sys
 
 import jax
 import pytest
@@ -17,8 +20,8 @@ from repro.core.islands import IslandConfig, IslandOptimizer
 from repro.core.scheduler import ShapeBucketScheduler
 from repro.functions import get
 
-SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                   "src", "repro")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SRC = os.path.join(ROOT, "src", "repro")
 P, D = 16, 4
 PHASES = {obs.VARIATION, obs.EVALUATE, obs.RETRY, obs.SELECT}
 
@@ -164,6 +167,59 @@ def test_a_capture_of_a_served_bucket_holds_the_scheduler_spans(tmp_path):
 
     spans = _host_spans(tmp_path, serve)
     assert {obs.SCHED_RUN, obs.ENGINE_STEP, obs.SCHED_PROGRESS} <= spans
+
+
+SYNC_PROGRAMS = """
+import json, re
+import jax
+from repro.core import de
+from repro.core.islands import IslandConfig, IslandOptimizer
+from repro.core.mesh import MeshConfig
+from repro.functions import get
+
+f = get("rastrigin", 4)
+out = {}
+for policy in ("barrier", "async"):
+    cfg = IslandConfig(n_islands=4, pop=16, dim=4, sync_every=3,
+                       migration="ring", share_incumbent=True,
+                       sync_policy=policy, max_evals=4 * 16 * 40)
+    for devices in (4, 1):
+        opt = IslandOptimizer(de.make, cfg, mesh_cfg=MeshConfig(devices))
+        _, many, _ = opt._many_fn(f)
+        keys = jax.random.split(jax.random.PRNGKey(0), 2)
+        args = [keys]
+        if opt._async:
+            args += opt._materialize_schedule(opt._budget(
+                *opt._eval_totals(opt._build(f)))[0])
+        text = many.lower(*args).compile().as_text()
+        synced = [line for line in text.splitlines()
+                  if re.search(r'op_name="[^"]*popt\\.sync', line)]
+        out[f"{policy}/{devices}"] = [len(synced), sorted(
+            {k for line in synced
+             for k in re.findall(r" (all-reduce|all-gather)(?:-start)?\\(",
+                                 line)})]
+print(json.dumps(out))
+"""
+
+
+def test_sync_scope_names_the_cross_chip_merge_and_only_that():
+    """``popt.sync`` is on the sharded program's cross-device merge of the
+    incumbent (the per-round ``pmin`` of the history point, the
+    ``share_incumbent`` all-gathers), barrier and async alike, and on no op
+    of the same run on a mesh of one device. The sharded programs need four
+    devices, so they compile in a child process that sees four CPU
+    devices."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(ROOT, "src"),
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
+                          + " --xla_force_host_platform_device_count=4"))
+    p = subprocess.run([sys.executable, "-c", SYNC_PROGRAMS], cwd=ROOT,
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-4000:]
+    synced = json.loads(p.stdout.strip().splitlines()[-1])
+    for policy in ("barrier", "async"):
+        assert synced[f"{policy}/4"][1] == ["all-gather", "all-reduce"], synced
+        assert synced[f"{policy}/1"][0] == 0, synced
 
 
 def test_every_name_lives_in_the_vocabulary_and_is_used():

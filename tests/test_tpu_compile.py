@@ -1,7 +1,8 @@
 """Compile the five fused kernels at Table I width (800x1000) for a described
-TPU v5e chip, with the tile the autotuner picks for the TPU; and Table I's
+TPU v5e chip, with the tile the autotuner picks for the TPU; Table I's
 whole-run program, whose phase scopes must change no op and whose chunks
-build only their own rows.
+build only their own rows; and eight Table I islands sharded over the
+described four chips, as the scheduler runs them.
 
 No chip is needed: the TPU compiler runs here against a described topology
 and raises what Mosaic would raise on the chip (tiling, layouts, VMEM, and
@@ -13,14 +14,18 @@ process at a time may load the TPU's library, and every test worker imports
 this file.
 """
 import contextlib
+import dataclasses
 import os
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
+from repro.core import mesh as mesh_mod
 from repro.core import obs
 from repro.core.api import OptRequest
 from repro.core.scheduler import build_optimizer
@@ -40,24 +45,30 @@ CASES = ([(k, t) for k in ("bench_eval", "de_step")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2, with JAX's persistent cache off
-    around the compiles (an entry written for a described chip cannot be
-    read back without one)."""
+def topo():
+    """A described v5e:2x2, with JAX's persistent cache off around the
+    compiles (an entry written for a described chip cannot be read back
+    without one)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        described = topologies.get_topology_desc(platform="tpu",
+                                                 topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler installed
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield described
     jax.config.update("jax_enable_compilation_cache", enabled)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One chip of the described v5e:2x2."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _entry(kind, tag, kc):
@@ -150,6 +161,42 @@ def test_table1_chunks_build_only_their_own_rows(one_chip):
     assert f"u32[{P},{D}]" not in text
     gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text)
     assert gathers == [f"f32[{P // 8},{D}]"] * 3, gathers
+
+
+ISLANDS8 = dataclasses.replace(TABLE1, n_islands=8, migration="ring",
+                               devices=4, max_evals=8 * P * 20_001)
+
+
+def test_served_ring_compiles_for_four_chips(topo, monkeypatch):
+    """Eight Table I islands over ``devices=4``, the scheduler's sharded
+    bucket (``minimize_many`` under ``shard_map``, one job), compiled for the
+    described four chips: the ring crosses chips as ``collective-permute``s
+    under ``popt.migrate`` in every round, the merge of the history point is
+    an all-reduce under ``popt.sync``, exactly one ``while`` sits directly
+    under ``popt.round``, and each chunk of each chip's two islands draws
+    bits and gathers donors for its own 100 rows alone."""
+    monkeypatch.setattr(mesh_mod.MeshConfig, "build", lambda self: Mesh(
+        np.asarray(topo.devices[: self.devices]), (self.axis,)))
+    opt, f = build_optimizer(ISLANDS8), get(ISLANDS8.fn, ISLANDS8.dim)
+    _, many, _ = opt._many_fn(f)
+    keys = jax.ShapeDtypeStruct((1, 2), jnp.uint32, sharding=NamedSharding(
+        Mesh(np.asarray(topo.devices), ("x",)), PartitionSpec()))
+    text = many.lower(keys).compile().as_text()
+
+    def named(pattern: str) -> list[str]:
+        return [n for line in text.splitlines() if re.search(pattern, line)
+                for n in re.findall(r'op_name="([^"]*)"', line)]
+
+    permutes = named(r" collective-permute-start\(")
+    assert permutes and all(
+        n.endswith(f"{obs.ROUND}/{obs.MIGRATE}/ppermute") for n in permutes)
+    assert any(obs.SYNC in n for n in named(r" all-reduce\("))
+    assert len([n for n in named(r"^\s*(ROOT )?%while\S* = ")
+                if n.endswith(obs.ROUND + "/while")]) == 1
+    assert not [n for n in named(rf"= u32\[1,2,{P},{D}\]") if obs.ROUND in n]
+    gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text)
+    assert gathers.count(f"f32[2,{P // 8},{D}]") == 3, gathers
+    assert not [g for g in gathers if g.endswith(f"{P},{D}]")], gathers
 
 
 def test_tile_scores_use_the_attached_tpu_row(monkeypatch):
