@@ -14,6 +14,12 @@ paper's "non-determinism-ok" flag:
                           population snapshot per generation (cheaper on TPU: no
                           second all-gather when the population axis is sharded).
 
+Key discipline: a generation's ``key`` splits into selection, crossover and
+forced-coordinate keys as in ``_trials``; chunk ``c`` of chunked mode keys on
+``fold_in(key, c)``. No key reads the population, so chunked mode draws every
+chunk's donors, forced coordinates and crossover key before its chunk loop
+(``_chunk_draws``), and a chunk hashes only its own crossover uniforms.
+
 ``fused=True`` routes the whole generation — mutation, crossover, evaluation,
 selection — through the fused ``kernels.de_step`` Pallas kernel (one HBM read /
 write of the population instead of five round-trips) via the engine's
@@ -28,6 +34,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.extend.random import threefry2x32_p
 
 from repro.core import obs
@@ -98,20 +105,41 @@ def _threefry_uniform_rows(k: Array, start: Array, n: int, D: int) -> Array:
     return jnp.maximum(0.0, jax.lax.bitcast_convert_type(bits, jnp.float32) - 1.0)
 
 
-def _chunk_trials(pop: Array, best: Array, key: Array, start: Array, n: int,
-                  w: float, px: float, strategy: str) -> Array:
+def _chunk_draws(key: Array, P: int, D: int, n: int, n_chunks: int
+                 ) -> tuple[tuple[Array, Array, Array], Array, Array]:
+    """Every chunk's donors, forced coordinates and crossover key, drawn once.
+
+    Chunk ``c`` keys on ``fold_in(key, c)`` and keeps rows ``[start_c,
+    start_c + n)``, ``start_c = min(c * n, P - n)``, of the P-row draws
+    ``_trials`` makes from it (the last chunk overlaps its predecessor when
+    ``n`` does not divide ``P``). No key reads the population, so one vmap
+    over ``c`` draws them all before the chunk loop; the starts are static,
+    so the rows are kept by a gather with constant indices (a
+    ``dynamic_slice`` with a batched start would lower to a loop per draw).
+    Returns ``(ra, rb, rc)``, ``jrand`` (each ``(n_chunks, n)``) and the
+    ``n_chunks`` crossover keys."""
+    def draw(c: Array) -> tuple[tuple[Array, ...], Array]:
+        ksel, kcr, kj = jax.random.split(jax.random.fold_in(key, c), 3)
+        return (*_distinct3(ksel, P), jax.random.randint(kj, (P,), 0, D)), kcr
+
+    rows, kcr = jax.vmap(draw)(jnp.arange(n_chunks))
+    idx = np.minimum(np.arange(n_chunks) * n, P - n)[:, None] + np.arange(n)
+    ra, rb, rc, jrand = (jnp.take_along_axis(r, idx, axis=1) for r in rows)
+    return (ra, rb, rc), jrand, kcr
+
+
+def _chunk_trials(pop: Array, best: Array, donors: tuple[Array, Array, Array],
+                  jrand: Array, kcr: Array, start: Array, w: float, px: float,
+                  strategy: str) -> Array:
     """Rows ``[start, start + n)`` of ``_trials(pop, best, key, ...)``, bit for
-    bit: the donors and forced coordinates are drawn for all P rows and
-    sliced (P scalars each), the crossover uniforms only for the block."""
-    P, D = pop.shape
-    ksel, kcr, kj = jax.random.split(key, 3)
-    ra, rb, rc = (jax.lax.dynamic_slice_in_dim(r, start, n)
-                  for r in _distinct3(ksel, P))
+    bit, from that chunk's rows of ``_chunk_draws``: the donors are gathered
+    from the current population, the crossover uniforms drawn for the block
+    alone (XLA fuses that hash into the trial's arithmetic)."""
+    ra, rb, rc = donors
+    n, D = ra.shape[0], pop.shape[1]
     base = pop[ra] if strategy == "rand1bin" else jnp.broadcast_to(best, (n, D))
     mutant = base + w * (pop[rb] - pop[rc])
-    cross = _uniform_rows(kcr, P, D, start, n) < px
-    jrand = jax.lax.dynamic_slice_in_dim(
-        jax.random.randint(kj, (P,), 0, D), start, n)
+    cross = _uniform_rows(kcr, pop.shape[0], D, start, n) < px
     cross = cross | (jnp.arange(D)[None, :] == jrand[:, None])
     return jnp.where(cross, mutant, jax.lax.dynamic_slice_in_dim(pop, start, n, 0))
 
@@ -157,16 +185,17 @@ def make(
     n_eff_chunks = (pop + csz - 1) // csz
 
     def gen_chunked(state: State, key: Array) -> State:
+        with obs.scope(obs.VARIATION):
+            donors, jrand, kcr = _chunk_draws(key, pop, dim, csz, n_eff_chunks)
+
         # Later chunks read earlier chunks' already-updated vectors ("stale-ok").
         def body(c: int, carry: tuple[Array, Array]) -> tuple[Array, Array]:
             p, fit = carry
             with obs.scope(obs.VARIATION):
-                ck = jax.random.fold_in(key, c)
-                # The last chunk overlaps its predecessor when csz does not
-                # divide pop; the draw's rows follow the same clamped start.
                 start = jnp.minimum(c * csz, pop - csz)
-                trial = clip_box(_chunk_trials(p, p[jnp.argmin(fit)], ck, start,
-                                               csz, w, px, strategy), lo, hi)
+                trial = clip_box(_chunk_trials(
+                    p, p[jnp.argmin(fit)], tuple(r[c] for r in donors),
+                    jrand[c], kcr[c], start, w, px, strategy), lo, hi)
             with obs.scope(obs.SELECT):
                 cur_f = jax.lax.dynamic_slice_in_dim(fit, start, csz, 0)
                 cur_p = jax.lax.dynamic_slice_in_dim(p, start, csz, 0)

@@ -277,6 +277,47 @@ def test_de_chunked_is_bit_identical_to_full_population_trials(pop, strategy,
                               np.asarray(jax.vmap(algo.init)(keys)["pop"]))
 
 
+def _primitives(jaxpr):
+    """The name of every primitive in ``jaxpr`` and the jaxprs nested in it."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    def subs(v):
+        if isinstance(v, ClosedJaxpr):
+            yield v.jaxpr
+        elif isinstance(v, Jaxpr):
+            yield v
+        elif isinstance(v, (tuple, list)):
+            for x in v:
+                yield from subs(x)
+
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in subs(tuple(eqn.params.values())):
+            yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("strategy", ["rand1bin", "best1bin"])
+@pytest.mark.parametrize("pop", [32, 37])
+def test_de_chunked_draws_every_chunk_before_the_loop(pop, strategy):
+    """No chunk key reads the population, so every key, donor and forced
+    coordinate is drawn before the chunk loop: its body hashes only the
+    chunk's crossover uniforms (one ``threefry2x32``) and derives, wraps or
+    draws no key (pop 37 has a clamped, overlapping last chunk)."""
+    from repro.core import de
+    f = get("rastrigin")
+    algo = de.make(f, jax.vmap(f.fn), pop, 11, strategy=strategy,
+                   barrier_mode="chunked")
+    state = jax.eval_shape(algo.init, jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(algo.gen)(state, jax.random.PRNGKey(1)).jaxpr
+    loops = [e for e in jaxpr.eqns if e.primitive.name in ("scan", "while")]
+    assert len(loops) == 1, loops
+    inside = list(_primitives(loops[0].params["jaxpr"].jaxpr))
+    assert inside.count("threefry2x32") == 1, inside
+    keys = {"random_fold_in", "random_split", "random_bits", "random_wrap"}
+    assert not keys & set(inside), inside
+    assert keys <= set(_primitives(jaxpr))
+
+
 @pytest.mark.parametrize("kind", ["raw", "typed", "rbg"])
 @pytest.mark.parametrize("partitionable", [True, False],
                          ids=["partitionable", "fallback"])

@@ -152,15 +152,54 @@ def test_table1_program_names_its_phases_and_changes_no_op(one_chip,
     assert _ops(_table1_text(one_chip)) == _ops(text)
 
 
+def _chunk_loop(text: str) -> tuple[list[str], list[str]]:
+    """The op lines of the chunk loop's body (the ``while`` nested in the one
+    directly under ``popt.round``), and those of every computation it calls
+    (its fusions' ops)."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) .*\{\s*$", line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+
+    def bodies(lines: list[str], suffix: str = "") -> list[str]:
+        return [re.search(r"body=%([^,\s]+)", line).group(1) for line in lines
+                if re.match(r"\s*(ROOT )?%while\S* = ", line)
+                and re.search(rf'op_name="[^"]*{re.escape(suffix)}"', line)]
+
+    (rnd,) = bodies([ln for lines in comps.values() for ln in lines],
+                    obs.ROUND + "/while")
+    (chunks,) = bodies(comps[rnd])
+    called, todo = [], list(comps[chunks])
+    while todo:
+        for callee in re.findall(r"(?:calls|to_apply)=%([^,\s}]+)", todo.pop()):
+            called += comps[callee]
+            todo += comps[callee]
+    return comps[chunks], called
+
+
 def test_table1_chunks_build_only_their_own_rows(one_chip):
     """On the chip's compiler each of Table I's chunks draws random bits and
     gathers donor rows for its own 100 rows alone: no op of the program holds
     the whole population's (800, 1000) bits, and every donor gather yields
-    (100, 1000)."""
+    (100, 1000). Every chunk's integer draws are made before the chunk loop,
+    whose body keeps few fusions."""
     text = _table1_text(one_chip)
     assert f"u32[{P},{D}]" not in text
-    gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text)
-    assert gathers == [f"f32[{P // 8},{D}]"] * 3, gathers
+    gather = r"= (\w+\[[\d,]*\])\S* gather\("
+    gathers = re.findall(gather, text)
+    donors = [f"f32[{P // 8},{D}]"] * 3
+    assert [g for g in gathers if g.startswith("f32")] == donors, gathers
+    body, called = _chunk_loop(text)
+    inside = [g for line in body + called for g in re.findall(gather, line)]
+    assert inside == donors, inside
+    fusions = [line for line in body if re.search(r" fusion\(", line)]
+    assert len(fusions) <= 24, len(fusions)
 
 
 ISLANDS8 = dataclasses.replace(TABLE1, n_islands=8, migration="ring",
@@ -197,6 +236,9 @@ def test_served_ring_compiles_for_four_chips(topo, monkeypatch):
     gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text)
     assert gathers.count(f"f32[2,{P // 8},{D}]") == 3, gathers
     assert not [g for g in gathers if g.endswith(f"{P},{D}]")], gathers
+    pop_copies = [line for line in _chunk_loop(text)[0]
+                  if re.search(rf"= \(f32\[1,2,{P},{D}\].* copy-start\(", line)]
+    assert not pop_copies, pop_copies
 
 
 def test_tile_scores_use_the_attached_tpu_row(monkeypatch):
