@@ -85,10 +85,10 @@ def straggler_bench(fn: str, *, islands: int, pop: int, dim: int,
     slower than the rest; compare **island-round throughput** of the barrier
     engine vs the async staleness-bounded engine under the same fault.
 
-    The fault is injected host-side through the engines' own hooks, not
-    modeled analytically: both runs go through the host-stepped round loop
-    (``round_callback``), where a per-round sleep stands in for the slow
-    island's extra compute.
+    The fault is injected host-side, not modeled analytically: both runs
+    step one job through the engine's host-stepped runner
+    (``BucketStepper``), where a sleep after each round stands in for the
+    slow island's extra compute.
 
     The straggler's step time is calibrated from a faultless timed run:
     ``fast_step = max(base_ms, measured per-tick compute)`` and the slow
@@ -96,10 +96,10 @@ def straggler_bench(fn: str, *, islands: int, pop: int, dim: int,
 
     * Barrier: the ``lax.ppermute`` round is a global barrier, so EVERY round
       waits the straggler's full step on top of its own compute — the
-      callback sleeps ``slow_factor*fast_step`` once per round, and all
+      host loop sleeps ``slow_factor*fast_step`` once per round, and all
       ``islands`` islands advance per round.
     * Async: the mailbox engine lets the fast islands tick at their own
-      cadence — the callback sleeps one ``fast_step`` per *tick*, and the
+      cadence — the host loop sleeps one ``fast_step`` per *tick*, and the
       straggler island steps only every ``slow_factor`` ticks
       (``AsyncSchedule.from_cadences``), exactly as many generations per
       wall-second as its 4x-slow hardware would manage.
@@ -121,12 +121,20 @@ def straggler_bench(fn: str, *, islands: int, pop: int, dim: int,
                                 max_staleness=slow_factor)
 
     def run(cfg, schedule, sleep_s):
-        hook = lambda r, ba, bv: time.sleep(sleep_s)  # noqa: E731
-        opt = IslandOptimizer(ALGORITHMS["de"], cfg, schedule=schedule,
-                              round_callback=hook)
-        opt.minimize(f, jax.random.PRNGKey(0))        # compile/warm
+        opt = IslandOptimizer(ALGORITHMS["de"], cfg, schedule=schedule)
+        stepper = opt.bucket_stepper(f)
+        keys = jax.random.PRNGKey(0)[None]
+
+        def one_run():
+            state, round_keys = stepper.init(keys)
+            for r in range(stepper.n_rounds):
+                state, point = stepper.step(state, round_keys, r)
+                point.block_until_ready()
+                time.sleep(sleep_s)
+
+        one_run()                                     # compile/warm
         t0 = time.perf_counter()
-        opt.minimize(f, jax.random.PRNGKey(0))
+        one_run()
         wall = time.perf_counter() - t0
         return opt, wall
 

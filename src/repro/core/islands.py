@@ -19,12 +19,13 @@ starvation and incumbent sharing degrade to all-gathers on the sync cadence.
 A fixed seed on a 1-device mesh is bit-identical to the unsharded engine —
 the determinism contract ``tests/test_distributed.py`` enforces.
 
-The engine is *device-resident* by default: the whole run is one jitted
+The engine is *device-resident*: the whole run is one jitted
 ``lax.scan`` over sync rounds with donated state and an on-device
 ``(n_rounds,)`` incumbent-history buffer, and results cross to the host
-exactly once at the end (DESIGN.md §4). Setting ``round_callback`` switches to
-the host-stepped loop — one jit call per round — so the driver can checkpoint,
-couple optimizers (ObserverHub), and survive restarts at round granularity.
+exactly once at the end (DESIGN.md §4). ``BucketStepper`` is the one
+host-stepped runner: it runs the same round body one jit call per round, so
+the service can stream progress, cancel, checkpoint and resume at round
+granularity.
 
 ``IslandConfig.polish`` turns any meta-heuristic into a *memetic hybrid*
 (DESIGN.md §6): every ``polish_every`` rounds, each island's ``polish_topk``
@@ -46,6 +47,7 @@ portfolio skips the switch and is bit-identical to the plain
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Any, Callable
@@ -208,7 +210,6 @@ class IslandOptimizer:
         mesh: Mesh | None = None,
         mesh_cfg: MeshConfig | None = None,
         exec_cfg: ExecutorConfig = ExecutorConfig(),
-        round_callback: Callable[[int, Array, Array], None] | None = None,
         schedule: AsyncSchedule | None = None,
     ) -> None:
         self.algo_maker = algo_maker
@@ -254,7 +255,6 @@ class IslandOptimizer:
         self.mesh = mesh
         self.mesh_cfg = mesh_cfg
         self.exec_cfg = exec_cfg
-        self.round_callback = round_callback
         # Island sharding (DESIGN.md §8): a MeshConfig lays the island axis
         # over a 1-D device mesh and the round scan runs under shard_map.
         self._island_mesh = None
@@ -322,72 +322,119 @@ class IslandOptimizer:
         return (algo.evals_per_gen * self.cfg.n_islands,
                 algo.init_evals * self.cfg.n_islands)
 
-    def _round_fn(self, algo) -> Callable[[State, Array], State]:
+    def _local(self, x: Array) -> Array:
+        """This shard's ``n_local``-row block of a replicated per-island table
+        (init and generation keys, branch table, schedule rows) under
+        ``shard_map`` — the same values the unsharded engine hands to
+        ``vmap``, which is the determinism contract of §8. Unsharded (or on
+        one shard) the table is already the shard's."""
+        if self._axis is None or self._n_shards == 1:
+            return x
+        n_local = self.cfg.n_islands // self._n_shards
+        start = jax.lax.axis_index(self._axis) * n_local
+        return jax.lax.dynamic_slice_in_dim(x, start, n_local, axis=0)
+
+    def _round_fn(self, algo) -> Callable[..., State]:
+        """One sync round, ``(state, round_key, step_row=None,
+        deliver_row=None) -> state``: ``sync_every`` generations, migration
+        with the adopted slots' aux re-init, and the incumbent merge.
+
+        Async mode (DESIGN.md §13) takes the schedule rows and differs in
+        four places only. The state carries the per-island mailbox leaves
+        (``migration.MAILBOX_KEYS``) beside the policy leaves. Islands not in
+        ``step_row`` keep their old leaves: the same global key table is
+        derived either way, so masked islands never perturb the key
+        discipline. Stepping islands post their best-k to their ring
+        successor's mailbox, gated by ``deliver_row``, and adopt the newest
+        batch at most ``max_staleness`` rounds stale. Per-island round
+        counters advance by ``step_row``. With all-ones rows every op reduces
+        to the barrier round's values: the ``max_staleness=0`` contract.
+        """
         from repro.core import portfolio as pf  # late: pf imports the algos
         cfg = self.cfg
         port = algo if cfg.portfolio else None
         stacked = cfg.n_islands > 1
         axis, n_shards = self._axis, self._n_shards
-        n_local = cfg.n_islands // n_shards
+        local = self._local
         if port is None:
             step = (algo.step_override if algo.step_override is not None
                     else algo.gen)
 
-        def _local_branch() -> Array | None:
-            # The (static, replicated) island->branch table; each shard takes
-            # its block, mirroring the key-table slicing below.
-            if port is None or port.n_branches == 1:
-                return None
-            br = jnp.asarray(port.branch_of)
-            if axis is not None and n_shards > 1:
-                br = _local_rows(br, axis, n_local)
-            return br
-
-        def round_fn(state: State, key: Array) -> State:
+        def round_fn(state: State, key: Array, step_row: Array | None = None,
+                     deliver_row: Array | None = None) -> State:
             with obs.scope(obs.ROUND):
-                return round_body(state, key)
+                return round_body(state, key, step_row, deliver_row)
 
-        def round_body(state: State, key: Array) -> State:
-            br = _local_branch()
+        def round_body(state: State, key: Array, step_row: Array | None,
+                       deliver_row: Array | None) -> State:
+            br = None
+            if port is not None and port.n_branches > 1:
+                br = local(jnp.asarray(port.branch_of))
+            if self._async:
+                step_row, deliver_row = local(step_row), local(deliver_row)
+                box = {k: state[k] for k in mig.MAILBOX_KEYS}
+                state = {k: v for k, v in state.items()
+                         if k not in mig.MAILBOX_KEYS}
 
             def one_gen(carry: State, k: Array) -> tuple[State, None]:
-                if stacked:
-                    # Every shard derives the SAME global (I, 2) key table and
-                    # takes its island block, so per-island key streams match
-                    # the unsharded engine exactly (determinism contract, §8).
-                    ks = jax.random.split(k, cfg.n_islands)
-                    if axis is not None and n_shards > 1:
-                        ks = _local_rows(ks, axis, n_local)
-                    if port is not None:
-                        return port.step_stacked(carry, ks, br), None
-                    return jax.vmap(step)(carry, ks), None
-                return step(carry, k), None
+                if not stacked:
+                    return step(carry, k), None
+                # Every shard derives the SAME global (I, 2) key table and
+                # takes its island block, so per-island key streams match
+                # the unsharded engine exactly (determinism contract, §8).
+                ks = local(jax.random.split(k, cfg.n_islands))
+                if port is not None:
+                    return port.step_stacked(carry, ks, br), None
+                return jax.vmap(step)(carry, ks), None
 
             gen_keys = jax.random.split(key, cfg.sync_every)
+            old = state
             state, _ = jax.lax.scan(one_gen, state, gen_keys)
+            if self._async:
+                # The step mask is constant across a tick's generations, so
+                # it is applied ONCE after the gens scan, never inside it:
+                # the inner scan body stays HLO-identical to the barrier
+                # round's, which is what makes the max_staleness=0
+                # degradation bit-exact (a select inside the loop body
+                # changes XLA fusion of the policy arithmetic and drifts pso
+                # by ulps). The select is pure data movement.
+                state = jax.tree.map(
+                    lambda a, b: jnp.where(
+                        step_row.reshape(step_row.shape + (1,) * (a.ndim - 1)),
+                        a, b),
+                    state, old)
 
             if stacked and cfg.migration != "none":
                 old_pop, old_fit = state["pop"], state["fit"]
-                if port is None:
-                    mig_alive = state.get("alive")
-                else:
-                    # Per-island liveness for the (global) starvation count:
-                    # policies that own an aging mask (ga) contribute it;
-                    # the rest contribute isfinite(fit) — exactly what the
-                    # plain engine's alive=None default computes, so a
-                    # homogeneous portfolio stays bit-identical even when
-                    # the executor has evicted candidates to +inf.
-                    oa = jnp.asarray(port.owns_alive)
-                    if axis is not None and n_shards > 1:
-                        oa = _local_rows(oa, axis, n_local)
-                    mig_alive = jnp.where(oa[:, None], state["alive"],
-                                          jnp.isfinite(state["fit"]))
+                if not self._async:
+                    if port is None:
+                        mig_alive = state.get("alive")
+                    else:
+                        # Per-island liveness for the (global) starvation
+                        # count: policies that own an aging mask (ga)
+                        # contribute it; the rest contribute isfinite(fit) —
+                        # exactly what the plain engine's alive=None default
+                        # computes, so a homogeneous portfolio stays
+                        # bit-identical even when the executor has evicted
+                        # candidates to +inf.
+                        oa = local(jnp.asarray(port.owns_alive))
+                        mig_alive = jnp.where(oa[:, None], state["alive"],
+                                              jnp.isfinite(state["fit"]))
                 with obs.scope(obs.MIGRATE):
-                    pop, fit = mig.migrate(
-                        cfg.migration, state["pop"], state["fit"],
-                        k=cfg.n_migrants, alive=mig_alive,
-                        axis=axis, n_shards=n_shards,
-                    )
+                    if self._async:
+                        box = mig.mailbox_post(
+                            box, old_pop, old_fit, cfg.n_migrants,
+                            step_row & deliver_row, axis=axis,
+                            n_shards=n_shards)
+                        pop, fit, box = mig.mailbox_adopt(
+                            box, old_pop, old_fit, cfg.max_staleness,
+                            step_row)
+                    else:
+                        pop, fit = mig.migrate(
+                            cfg.migration, old_pop, old_fit,
+                            k=cfg.n_migrants, alive=mig_alive,
+                            axis=axis, n_shards=n_shards,
+                        )
                     state = {**state, "pop": pop, "fit": fit}
                     if port is not None or pf.has_adopt_state(algo.name):
                         # Migration carries pos/fit only; slots whose values
@@ -424,116 +471,12 @@ class IslandOptimizer:
                     "best_val": jnp.full_like(bv, gbv[gi]),
                     "best_arg": jnp.broadcast_to(gba[gi], ba.shape),
                 }
+
+            if self._async:
+                box = {**box, "round_ctr":
+                       box["round_ctr"] + step_row.astype(jnp.int32)}
+                state = {**state, **box}
             return state
-
-        return round_fn
-
-    def _async_round_fn(self, algo) -> Callable[[State, Array, Array, Array], State]:
-        """The async sibling of :meth:`_round_fn` (DESIGN.md §13):
-        ``(state, round_key, step_row, deliver_row) -> state``.
-
-        The state carries the per-island mailbox leaves
-        (``migration.MAILBOX_KEYS``) alongside the policy leaves. Each tick:
-        islands selected by ``step_row`` run ``sync_every`` generations (the
-        rest keep their exact old leaves — the same global key table is
-        derived either way, so masked islands never perturb the key
-        discipline); stepping islands post their best-k to their ring
-        successor's mailbox gated by ``deliver_row`` and adopt the newest
-        batch at most ``max_staleness`` rounds stale; per-island round
-        counters advance by ``step_row``. With all-ones masks every op
-        reduces to the barrier round body's values, which is the
-        ``max_staleness=0`` degradation contract.
-        """
-        from repro.core import portfolio as pf  # late: pf imports the algos
-        cfg = self.cfg
-        port = algo if cfg.portfolio else None
-        axis, n_shards = self._axis, self._n_shards
-        n_local = cfg.n_islands // n_shards
-        if port is None:
-            step = (algo.step_override if algo.step_override is not None
-                    else algo.gen)
-
-        def local(x: Array) -> Array:
-            if axis is not None and n_shards > 1:
-                return _local_rows(x, axis, n_local)
-            return x
-
-        def round_fn(state: State, rk: Array, step_g: Array,
-                     deliver_g: Array) -> State:
-            with obs.scope(obs.ROUND):
-                return round_body(state, rk, step_g, deliver_g)
-
-        def round_body(state: State, rk: Array, step_g: Array,
-                       deliver_g: Array) -> State:
-            br = None
-            if port is not None and port.n_branches > 1:
-                br = local(jnp.asarray(port.branch_of))
-            step_row, deliver_row = local(step_g), local(deliver_g)
-            policy = {k: v for k, v in state.items()
-                      if k not in mig.MAILBOX_KEYS}
-            box = {k: state[k] for k in mig.MAILBOX_KEYS}
-
-            def one_gen(carry: State, k: Array) -> tuple[State, None]:
-                ks = local(jax.random.split(k, cfg.n_islands))
-                if port is not None:
-                    return port.step_stacked(carry, ks, br), None
-                return jax.vmap(step)(carry, ks), None
-
-            gen_keys = jax.random.split(rk, cfg.sync_every)
-            # The step mask is constant across a tick's generations, so it is
-            # applied ONCE after the gens scan, never inside it: the inner
-            # scan body stays HLO-identical to the barrier engine's, which is
-            # what makes the max_staleness=0 degradation bit-exact (a select
-            # inside the loop body changes XLA fusion of the policy
-            # arithmetic and drifts pso by ulps). The select itself is pure
-            # data movement — non-stepping islands keep their exact leaves.
-            old_policy = policy
-            policy, _ = jax.lax.scan(one_gen, policy, gen_keys)
-            policy = jax.tree.map(
-                lambda a, b: jnp.where(
-                    step_row.reshape(step_row.shape + (1,) * (a.ndim - 1)),
-                    a, b),
-                policy, old_policy)
-
-            if cfg.migration == "ring":
-                with obs.scope(obs.MIGRATE):
-                    old_pop, old_fit = policy["pop"], policy["fit"]
-                    box = mig.mailbox_post(
-                        box, old_pop, old_fit, cfg.n_migrants,
-                        step_row & deliver_row, axis=axis, n_shards=n_shards)
-                    pop, fit, box = mig.mailbox_adopt(
-                        box, old_pop, old_fit, cfg.max_staleness, step_row)
-                    policy = {**policy, "pop": pop, "fit": fit}
-                    if port is not None or pf.has_adopt_state(algo.name):
-                        # Same adopted-slot detection + aux re-init as the
-                        # barrier round body (DESIGN.md §10).
-                        adopted = (jnp.any(pop != old_pop, axis=-1)
-                                   | (fit != old_fit))
-                        if port is not None:
-                            policy = port.adopt_stacked(policy, adopted, br)
-                        else:
-                            policy = jax.vmap(
-                                partial(pf.adopt_native, algo.name))(
-                                    policy, adopted)
-
-            if cfg.share_incumbent:
-                bv, ba = policy["best_val"], policy["best_arg"]
-                if axis is not None and n_shards > 1:
-                    with obs.scope(obs.SYNC):
-                        gbv = jax.lax.all_gather(bv, axis, tiled=True)
-                        gba = jax.lax.all_gather(ba, axis, tiled=True)
-                else:
-                    gbv, gba = bv, ba
-                gi = jnp.argmin(gbv)
-                policy = {
-                    **policy,
-                    "best_val": jnp.full_like(bv, gbv[gi]),
-                    "best_arg": jnp.broadcast_to(gba[gi], ba.shape),
-                }
-
-            box = {**box,
-                   "round_ctr": box["round_ctr"] + step_row.astype(jnp.int32)}
-            return {**policy, **box}
 
         return round_fn
 
@@ -583,20 +526,24 @@ class IslandOptimizer:
 
     def _scan_rounds(
         self, algo, polish_pass: Callable[[State], State] | None,
-    ) -> Callable[[State, Array], tuple[State, Array]]:
-        """Per-shard round scan ``(state, round_keys) -> (state, history)`` —
-        the body both the unsharded run and the ``shard_map``-wrapped sharded
-        run execute (polish on its cadence, per-round incumbent history)."""
+    ) -> Callable[..., tuple[State, Array]]:
+        """Per-shard round scan ``(state, round_keys[, step, deliver]) ->
+        (state, history)`` — the body both the unsharded run and the
+        ``shard_map``-wrapped sharded run execute (polish on its cadence,
+        per-round incumbent history). Async mode passes the schedule masks,
+        which join the scan's per-round inputs, so one compiled program
+        serves every schedule."""
         cfg = self.cfg
         stacked = cfg.n_islands > 1
         every = max(1, cfg.polish_every)
         axis, n_shards = self._axis, self._n_shards
         round_fn = self._round_fn(algo)
 
-        def scan_rounds(state: State, round_keys: Array) -> tuple[State, Array]:
-            def body(carry: State, xs: tuple[Array, Array]) -> tuple[State, Array]:
-                rk, r = xs
-                carry = round_fn(carry, rk)
+        def scan_rounds(state: State, round_keys: Array,
+                        *masks: Array) -> tuple[State, Array]:
+            def body(carry: State, xs: tuple) -> tuple[State, Array]:
+                rk, r, *rows = xs
+                carry = round_fn(carry, rk, *rows)
                 if polish_pass is not None:
                     carry = jax.lax.cond(
                         (r + 1) % every == 0, polish_pass, lambda s: s, carry)
@@ -608,88 +555,35 @@ class IslandOptimizer:
                 return carry, point
 
             rs = jnp.arange(round_keys.shape[0])
-            return jax.lax.scan(body, state, (round_keys, rs))
-
-        return scan_rounds
-
-    def _async_scan_rounds(
-        self, algo, polish_pass: Callable[[State], State] | None,
-    ) -> Callable[[State, Array, Array, Array], tuple[State, Array]]:
-        """Async sibling of :meth:`_scan_rounds`: the schedule masks join the
-        scan's per-tick inputs — ``(state, round_keys, step, deliver) ->
-        (state, history)`` — so one compiled program serves every schedule."""
-        cfg = self.cfg
-        every = max(1, cfg.polish_every)
-        axis, n_shards = self._axis, self._n_shards
-        round_fn = self._async_round_fn(algo)
-
-        def scan_rounds(state: State, round_keys: Array, step_m: Array,
-                        deliver_m: Array) -> tuple[State, Array]:
-            def body(carry: State, xs) -> tuple[State, Array]:
-                rk, r, srow, drow = xs
-                carry = round_fn(carry, rk, srow, drow)
-                if polish_pass is not None:
-                    carry = jax.lax.cond(
-                        (r + 1) % every == 0, polish_pass, lambda s: s, carry)
-                point = jnp.min(carry["best_val"])
-                if axis is not None and n_shards > 1:
-                    with obs.scope(obs.SYNC):
-                        point = jax.lax.pmin(point, axis)
-                return carry, point
-
-            rs = jnp.arange(round_keys.shape[0])
-            return jax.lax.scan(body, state, (round_keys, rs, step_m, deliver_m))
+            return jax.lax.scan(body, state, (round_keys, rs, *masks))
 
         return scan_rounds
 
     def _run_fn(
         self, algo, polish_pass: Callable[[State], State] | None = None,
     ) -> Callable[..., tuple]:
-        """Whole-run device program: scan over sync rounds (polishing on the
-        ``polish_every`` cadence), select the global incumbent on device,
-        return ``(best_arg, best_val, history)``. With an island mesh the scan
-        runs under ``shard_map`` (one shard per island block) and the final
-        selection happens on the reassembled global state.
-
-        The async engine's program additionally takes the schedule masks and
-        returns the adopted-staleness high-water mark as a fourth output:
-        ``(state, round_keys, step, deliver) -> (arg, val, history, stale)``.
-        """
+        """Whole-run device program ``(state, round_keys[, step, deliver]) ->
+        (best_arg, best_val, history[, stale])``: scan over sync rounds
+        (polishing on the ``polish_every`` cadence) and select the global
+        incumbent on device. With an island mesh the scan runs under
+        ``shard_map`` (one shard per island block) and the final selection
+        happens on the reassembled global state. Async mode takes the
+        schedule masks and also returns the adopted-staleness high-water
+        mark."""
         stacked = self.cfg.n_islands > 1
-        if self._async:
-            scan_rounds = self._async_scan_rounds(algo, polish_pass)
-            if self._island_mesh is None:
-                body = scan_rounds
-            else:
-                in_specs, out_specs = mesh_mod.island_specs(self._axis, 3)
-                body = mesh_mod.shard_map(
-                    scan_rounds, self._island_mesh,
-                    in_specs=in_specs, out_specs=out_specs)
+        body = self._scan_rounds(algo, polish_pass)
+        if self._island_mesh is not None:
+            in_specs, out_specs = mesh_mod.island_specs(
+                self._axis, 3 if self._async else 1)
+            body = mesh_mod.shard_map(
+                body, self._island_mesh,
+                in_specs=in_specs, out_specs=out_specs)
 
-            def run_async(state: State, round_keys: Array, step_m: Array,
-                          deliver_m: Array):
-                state, history = body(state, round_keys, step_m, deliver_m)
-                arg, val = _select_best(state, stacked)
-                return arg, val, history, jnp.max(state["stale_seen"])
-
-            return run_async
-
-        scan_rounds = self._scan_rounds(algo, polish_pass)
-        if self._island_mesh is None:
-            def run(state: State, round_keys: Array) -> tuple[Array, Array, Array]:
-                state, history = scan_rounds(state, round_keys)
-                arg, val = _select_best(state, stacked)
-                return arg, val, history
-            return run
-
-        in_specs, out_specs = mesh_mod.island_specs(self._axis, 1)
-        sharded = mesh_mod.shard_map(
-            scan_rounds, self._island_mesh,
-            in_specs=in_specs, out_specs=out_specs)
-
-        def run(state: State, round_keys: Array) -> tuple[Array, Array, Array]:
-            state, history = sharded(state, round_keys)
+        def run(state: State, round_keys: Array, *masks: Array) -> tuple:
+            state, history = body(state, round_keys, *masks)
             arg, val = _select_best(state, stacked)
+            if self._async:
+                return arg, val, history, jnp.max(state["stale_seen"])
             return arg, val, history
 
         return run
@@ -710,21 +604,29 @@ class IslandOptimizer:
 
         return jax.tree.map(put, state)
 
-    def _init_state(self, algo, ik: Array) -> State:
+    def _init_state(self, algo, ik: Array, local: bool = False) -> State:
         """Fresh engine state from init key ``ik`` — the one init rule every
-        path (minimize, jobs axis, host stepper) shares. Async mode merges
-        the mailbox leaves (``migration.mailbox_init``) into the state dict,
-        so checkpointing and sharding see one pytree."""
+        path (minimize, jobs axis, host stepper) shares. ``local`` builds
+        only this shard's islands under ``shard_map``: its rows of the global
+        init-key and branch tables, and an ``n_local``-island mailbox. Async
+        mode merges the mailbox leaves (``migration.mailbox_init``) into the
+        state dict, so checkpointing and sharding see one pytree."""
         cfg = self.cfg
-        if cfg.portfolio:
-            state = algo.init_stacked(jax.random.split(ik, cfg.n_islands))
-        elif cfg.n_islands > 1:
-            state = jax.vmap(algo.init)(jax.random.split(ik, cfg.n_islands))
-        else:
+        rows = self._local if local else (lambda x: x)
+        if cfg.n_islands == 1:
             state = algo.init(ik)
+        else:
+            iks = rows(jax.random.split(ik, cfg.n_islands))
+            if cfg.portfolio:
+                br = (rows(jnp.asarray(algo.branch_of))
+                      if algo.n_branches > 1 else None)
+                state = algo.init_stacked(iks, br)
+            else:
+                state = jax.vmap(algo.init)(iks)
         if self._async:
+            n = cfg.n_islands // self._n_shards if local else cfg.n_islands
             state = {**state, **mig.mailbox_init(
-                cfg.n_islands, cfg.mailbox_slots, cfg.n_migrants, cfg.dim)}
+                n, cfg.mailbox_slots, cfg.n_migrants, cfg.dim)}
         return state
 
     def _warm_fn(self, f: Function, algo) -> Callable[[State, Array, Array], State]:
@@ -835,27 +737,17 @@ class IslandOptimizer:
 
     def minimize(self, f: Function, key: Array,
                  warm: Any = None) -> OptimizeResult:
-        """Run the full eval budget on objective ``f`` from PRNG ``key``.
-
-        Device-resident (one jitted scan, one host transfer) unless
-        ``round_callback`` is set; either path yields the same trajectory for
-        a fixed key — including the polish cadence when ``cfg.polish`` is on.
+        """Run the full eval budget on objective ``f`` from PRNG ``key`` as
+        one device-resident program: one jitted scan, one host transfer at
+        the end. :class:`BucketStepper` steps the same rounds from the host
+        when a caller needs round granularity.
 
         ``warm`` (optional, (W, dim)) are externally-routed immigrants —
         federation migrants — adopted into the initial population before
         round 0 (see :meth:`_warm_fn`).
         """
         cfg = self.cfg
-        if self.round_callback is not None and self._island_mesh is not None:
-            raise ValueError(
-                "round_callback requires the unsharded engine — the "
-                "host-stepped loop cannot run inside shard_map (DESIGN.md §8)")
-        if self.round_callback is None:
-            algo, run, pp = self._single_fn(f)
-            polish_pass = None
-        else:
-            algo, run = self._build(f), None
-            polish_pass, pp = self._polish(f)
+        algo, run, pp = self._single_fn(f)
         per_gen_total, init_total = self._eval_totals(algo)
         n_rounds, per_round, n_polish, per_polish = self._budget(
             per_gen_total, init_total, pp)
@@ -867,59 +759,27 @@ class IslandOptimizer:
                 state = self._inject_warm(f, algo, state, warm)
             state = self._shard_state(state)
             round_keys = _chain_split(key, n_rounds)
-            if self._async:
-                step_m, deliver_m = self._materialize_schedule(n_rounds)
+            masks = self._materialize_schedule(n_rounds) if self._async else ()
 
-        ctx = self.mesh if self.mesh is not None else _nullcontext()
-        with ctx:
-            if self.round_callback is None:
-                # Device-resident path: one jit, one host pull at the end.
-                if self._async:
-                    with obs.span(obs.ENGINE_DISPATCH):
-                        out = run(state, round_keys, step_m, deliver_m)
-                    with obs.span(obs.ENGINE_FETCH):
-                        arg, val, history, stale = jax.device_get(out)
-                    self.last_max_staleness = int(stale)
-                else:
-                    with obs.span(obs.ENGINE_DISPATCH):
-                        out = run(state, round_keys)
-                    with obs.span(obs.ENGINE_FETCH):
-                        arg, val, history = jax.device_get(out)
-            else:
-                # Host-stepped path: round granularity for checkpoint/coupling.
-                # Polish applies on the same cadence, BEFORE the history/
-                # callback read, mirroring the device-resident scan body.
-                if self._async:
-                    around = jax.jit(self._async_round_fn(algo),
-                                     donate_argnums=0)
-                    round_jit = lambda s, r: around(  # noqa: E731
-                        s, round_keys[r], step_m[r], deliver_m[r])
-                else:
-                    brond = jax.jit(self._round_fn(algo), donate_argnums=0)
-                    round_jit = lambda s, r: brond(s, round_keys[r])  # noqa: E731
-                polish_jit = (jax.jit(polish_pass, donate_argnums=0)
-                              if polish_pass is not None else None)
-                every = max(1, cfg.polish_every)
-                history = []
-                for r in range(n_rounds):
-                    state = round_jit(state, r)
-                    if polish_jit is not None and (r + 1) % every == 0:
-                        state = polish_jit(state)
-                    bv = state["best_val"]
-                    gval = jnp.min(bv) if cfg.n_islands > 1 else bv
-                    history.append(float(gval))
-                    self.round_callback(r, state["best_arg"], state["best_val"])
-                if self._async:
-                    self.last_max_staleness = int(
-                        jnp.max(state["stale_seen"]))
-                arg, val = _select_best(state, cfg.n_islands > 1)
-                history = np.asarray(history, dtype=np.float32)
-
+        arg, val, history = self._dispatch(run, state, round_keys, *masks)
         n_evals = (init_total + n_rounds * per_round + n_polish * per_polish)
         return OptimizeResult(
             arg=arg, value=float(val), n_evals=n_evals,
             n_gens=n_rounds * cfg.sync_every, history=history,
         )
+
+    def _dispatch(self, run: Callable, *args: Any) -> list:
+        """Call a resident program once and fetch its outputs in one host
+        transfer. The async program's last output, the adopted-staleness
+        high-water mark, goes to ``last_max_staleness``."""
+        with self.mesh if self.mesh is not None else contextlib.nullcontext():
+            with obs.span(obs.ENGINE_DISPATCH):
+                out = run(*args)
+            with obs.span(obs.ENGINE_FETCH):
+                out = list(jax.device_get(out))
+        if self._async:
+            self.last_max_staleness = int(np.max(out.pop()))
+        return out
 
     def bucket_stepper(self, f: Function) -> "BucketStepper":
         """Cached host-stepped jobs-axis runner for objective ``f`` — the
@@ -937,9 +797,10 @@ class IslandOptimizer:
     # -- jobs axis ---------------------------------------------------------
 
     def _many_fn(self, f: Function) -> tuple[MetaHeuristic, Callable, int]:
-        """Compiled jobs-axis runner for objective ``f``: ``keys (J, 2) ->
-        (args (J, dim), vals (J,), histories (J, n_rounds))``, plus the
-        polish evals/point for budget accounting.
+        """Compiled jobs-axis runner for objective ``f``: ``keys (J, 2)[,
+        step, deliver] -> (args (J, dim), vals (J,), histories (J,
+        n_rounds)[, stale])``, plus the polish evals/point for budget
+        accounting.
 
         Each job replays ``minimize``'s exact device program — the same
         ``split``/``_chain_split`` key discipline, init, round scan and
@@ -947,6 +808,8 @@ class IslandOptimizer:
         standalone ``minimize`` call with the same key. ``vmap`` over jobs
         composes outside the per-island ``vmap`` and the executor's
         ``shard_map``: J same-shaped jobs cost one dispatch instead of J.
+        Async jobs share one (replicated) schedule; the masks are data, so
+        one compiled program serves every schedule of this length.
 
         With an island mesh the jobs-axis ``vmap`` moves *inside* the
         ``shard_map``: every shard initializes and steps its own island block
@@ -958,105 +821,38 @@ class IslandOptimizer:
         if hit is not None and hit[0] is f.fn:
             return hit[1], hit[2], hit[3]
 
-        cfg = self.cfg
         algo = self._build(f)
         polish_pass, pp = self._polish(f)
         n_rounds, _, _, _ = self._budget(*self._eval_totals(algo), pp)
-        stacked = cfg.n_islands > 1
+        in_axes = (0, None, None) if self._async else (0,)
 
-        if self._async and self._island_mesh is None:
-            # Async jobs axis: every job replays minimize's async program
-            # under one shared (replicated) schedule; the masks are data, so
-            # one compiled program serves every schedule of this length.
+        if self._island_mesh is None:
             run = self._run_fn(algo, polish_pass)
 
-            def one_job_async(k: Array, step_m: Array, deliver_m: Array):
+            def one_job(k: Array, *masks: Array) -> tuple:
                 key, ik = jax.random.split(k)
-                state = self._init_state(algo, ik)
-                return run(state, _chain_split(key, n_rounds),
-                           step_m, deliver_m)
+                return run(self._init_state(algo, ik),
+                           _chain_split(key, n_rounds), *masks)
 
-            many = jax.jit(jax.vmap(one_job_async, in_axes=(0, None, None)))
-        elif self._async:
-            axis, n_shards = self._axis, self._n_shards
-            n_local = cfg.n_islands // n_shards
-            scan_rounds = self._async_scan_rounds(algo, polish_pass)
-
-            def one_job_local_async(k: Array, step_m: Array, deliver_m: Array):
-                key, ik = jax.random.split(k)
-                iks = jax.random.split(ik, cfg.n_islands)
-                if n_shards > 1:
-                    iks = _local_rows(iks, axis, n_local)
-                if cfg.portfolio:
-                    br = None
-                    if algo.n_branches > 1:
-                        br = jnp.asarray(algo.branch_of)
-                        if n_shards > 1:
-                            br = _local_rows(br, axis, n_local)
-                    state = algo.init_stacked(iks, br)
-                else:
-                    state = jax.vmap(algo.init)(iks)
-                state = {**state, **mig.mailbox_init(
-                    n_local, cfg.mailbox_slots, cfg.n_migrants, cfg.dim)}
-                return scan_rounds(state, _chain_split(key, n_rounds),
-                                   step_m, deliver_m)
-
-            sharded = mesh_mod.shard_map(
-                jax.vmap(one_job_local_async, in_axes=(0, None, None)),
-                self._island_mesh,
-                in_specs=(P(), P(), P()), out_specs=(P(None, axis), P()))
-
-            def many_sharded_async(keys: Array, step_m: Array,
-                                   deliver_m: Array):
-                state, hists = sharded(keys, step_m, deliver_m)
-                args, vals = jax.vmap(lambda s: _select_best(s, True))(state)
-                return args, vals, hists, jnp.max(state["stale_seen"])
-
-            many = jax.jit(many_sharded_async)
-        elif self._island_mesh is None:
-            run = self._run_fn(algo, polish_pass)
-
-            def one_job(k: Array) -> tuple[Array, Array, Array]:
-                key, ik = jax.random.split(k)
-                if cfg.portfolio:
-                    state = algo.init_stacked(
-                        jax.random.split(ik, cfg.n_islands))
-                elif stacked:
-                    state = jax.vmap(algo.init)(
-                        jax.random.split(ik, cfg.n_islands))
-                else:
-                    state = algo.init(ik)
-                return run(state, _chain_split(key, n_rounds))
-
-            many = jax.jit(jax.vmap(one_job))
+            many = jax.jit(jax.vmap(one_job, in_axes=in_axes))
         else:
-            axis, n_shards = self._axis, self._n_shards
-            n_local = cfg.n_islands // n_shards
             scan_rounds = self._scan_rounds(algo, polish_pass)
 
-            def one_job_local(k: Array) -> tuple[State, Array]:
+            def one_job_local(k: Array, *masks: Array) -> tuple[State, Array]:
                 key, ik = jax.random.split(k)
-                iks = jax.random.split(ik, cfg.n_islands)
-                if n_shards > 1:
-                    iks = _local_rows(iks, axis, n_local)
-                if cfg.portfolio:
-                    br = None
-                    if algo.n_branches > 1:
-                        br = jnp.asarray(algo.branch_of)
-                        if n_shards > 1:
-                            br = _local_rows(br, axis, n_local)
-                    state = algo.init_stacked(iks, br)
-                else:
-                    state = jax.vmap(algo.init)(iks)
-                return scan_rounds(state, _chain_split(key, n_rounds))
+                return scan_rounds(self._init_state(algo, ik, local=True),
+                                   _chain_split(key, n_rounds), *masks)
 
             sharded = mesh_mod.shard_map(
-                jax.vmap(one_job_local), self._island_mesh,
-                in_specs=(P(),), out_specs=(P(None, axis), P()))
+                jax.vmap(one_job_local, in_axes=in_axes), self._island_mesh,
+                in_specs=(P(),) * len(in_axes),
+                out_specs=(P(None, self._axis), P()))
 
-            def many_sharded(keys: Array) -> tuple[Array, Array, Array]:
-                state, hists = sharded(keys)        # (J, I, ...), (J, R)
+            def many_sharded(keys: Array, *masks: Array) -> tuple:
+                state, hists = sharded(keys, *masks)   # (J, I, ...), (J, R)
                 args, vals = jax.vmap(lambda s: _select_best(s, True))(state)
+                if self._async:
+                    return args, vals, hists, jnp.max(state["stale_seen"])
                 return args, vals, hists
 
             many = jax.jit(many_sharded)
@@ -1072,9 +868,6 @@ class IslandOptimizer:
         ``cfg.island_axes`` — the multi-job analogue of island sharding.
         """
         cfg = self.cfg
-        if self.round_callback is not None:
-            raise ValueError("minimize_many is device-resident only; "
-                             "round_callback requires per-job minimize calls")
         algo, many, pp = self._many_fn(f)
         per_gen_total, init_total = self._eval_totals(algo)
         n_rounds, per_round, n_polish, per_polish = self._budget(
@@ -1095,20 +888,8 @@ class IslandOptimizer:
                     [keys, jnp.broadcast_to(keys[:1], (pad, *keys.shape[1:]))])
             keys = jax.device_put(
                 keys, NamedSharding(self.mesh, P(cfg.island_axes, None)))
-        ctx = self.mesh if self.mesh is not None else _nullcontext()
-        with ctx:
-            if self._async:
-                step_m, deliver_m = self._materialize_schedule(n_rounds)
-                with obs.span(obs.ENGINE_DISPATCH):
-                    out = many(keys, step_m, deliver_m)
-                with obs.span(obs.ENGINE_FETCH):
-                    args, vals, hists, stale = jax.device_get(out)
-                self.last_max_staleness = int(np.max(stale))
-            else:
-                with obs.span(obs.ENGINE_DISPATCH):
-                    out = many(keys)
-                with obs.span(obs.ENGINE_FETCH):
-                    args, vals, hists = jax.device_get(out)
+        masks = self._materialize_schedule(n_rounds) if self._async else ()
+        args, vals, hists = self._dispatch(many, keys, *masks)
 
         n_evals = (init_total + n_rounds * per_round + n_polish * per_polish)
         return [
@@ -1123,6 +904,9 @@ class IslandOptimizer:
 class BucketStepper:
     """Host-stepped jobs-axis runner — ``minimize_many``'s exact per-round
     program, advanced one sync round at a time from the host (DESIGN.md §12).
+    It is the engine's only host-stepped runner: ``minimize`` and
+    ``minimize_many`` are device-resident, and a caller that needs round
+    granularity steps a bucket of one or more jobs through this class.
 
     The service layer's hardening primitive: because control returns to the
     host at every round boundary, a bucket run can stream per-round incumbent
@@ -1161,18 +945,17 @@ class BucketStepper:
         self.every = max(1, cfg.polish_every)
         self.has_polish = polish_pass is not None
         stacked = cfg.n_islands > 1
-        if opt._async:
-            # Scheduler-driven async buckets run the deterministic barrier-
-            # cadence schedule (all-ones masks, the AsyncSchedule default):
-            # the resident async program under the default schedule computes
-            # the same values, so the stepped-vs-resident bit-identity
-            # contract (DESIGN.md §12) extends to async buckets.
-            async_round = opt._async_round_fn(algo)
-            ones = jnp.ones((cfg.n_islands,), bool)
-            round_fn = lambda s, rk: async_round(s, rk, ones, ones)  # noqa: E731
-        else:
-            round_fn = opt._round_fn(algo)
         n_rounds = self.n_rounds
+        round_fn = opt._round_fn(algo)
+        # Async buckets step under the optimizer's schedule: all-ones masks
+        # (the barrier cadence, what scheduler-driven buckets run) unless an
+        # AsyncSchedule was given. The resident async program computes the
+        # same values under the same masks, so the stepped-vs-resident
+        # bit-identity contract (DESIGN.md §12) extends to async buckets.
+        self._masks = (tuple(np.asarray(m) for m in
+                             opt._materialize_schedule(n_rounds))
+                       if opt._async else None)
+        in_axes = (0, 0, None, None) if opt._async else (0, 0)
         # Warm-start immigration (launch/federate.py): jitted lazily on the
         # first bucket that actually carries warm candidates.
         self._warm_fn = opt._warm_fn(f, algo)
@@ -1193,14 +976,15 @@ class BucketStepper:
             bv = state["best_val"]
             return jnp.min(bv, axis=-1) if stacked else bv
 
-        def step(state: State, rk: Array) -> tuple[State, Array]:
-            state = jax.vmap(round_fn)(state, rk)
+        def step(state: State, rk: Array, *rows: Array) -> tuple[State, Array]:
+            state = jax.vmap(round_fn, in_axes=in_axes)(state, rk, *rows)
             return state, point(state)
 
-        def step_polish(state: State, rk: Array) -> tuple[State, Array]:
+        def step_polish(state: State, rk: Array,
+                        *rows: Array) -> tuple[State, Array]:
             # Polish BEFORE the history point is read — the device-resident
             # scan body's order (round_fn -> cond polish -> point).
-            state = jax.vmap(round_fn)(state, rk)
+            state = jax.vmap(round_fn, in_axes=in_axes)(state, rk, *rows)
             state = jax.vmap(polish_pass)(state)
             return state, point(state)
 
@@ -1251,8 +1035,10 @@ class BucketStepper:
         callers must not reuse the argument after the call."""
         fn = (self._step_polish
               if self.has_polish and (r + 1) % self.every == 0 else self._step)
+        rows = () if self._masks is None else (self._masks[0][r],
+                                               self._masks[1][r])
         with obs.span(obs.ENGINE_STEP):
-            return fn(state, round_keys[:, r])
+            return fn(state, round_keys[:, r], *rows)
 
     def best(self, state: State) -> tuple[Array, Array]:
         """Per-job global incumbent ``(args (J, dim), vals (J,))`` from the
@@ -1264,14 +1050,6 @@ class BucketStepper:
         the same accounting rule ``minimize_many`` charges at full budget."""
         n_polish = rounds // self.every if self.has_polish else 0
         return self.init_evals + rounds * self.per_round + n_polish * self.per_polish
-
-
-def _local_rows(x: Array, axis: str, n_local: int) -> Array:
-    """This shard's ``n_local``-row block of a replicated per-island table —
-    how a shard under ``shard_map`` picks its islands' keys out of the global
-    key table (same values the unsharded engine hands to ``vmap``)."""
-    start = jax.lax.axis_index(axis) * n_local
-    return jax.lax.dynamic_slice_in_dim(x, start, n_local, axis=0)
 
 
 def _select_best(state: State, stacked: bool) -> tuple[Array, Array]:
@@ -1296,14 +1074,6 @@ def _chain_split(key: Array, n: int) -> Array:
 
     _, rks = jax.lax.scan(body, key, None, length=n)
     return rks
-
-
-class _nullcontext:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *a):
-        return False
 
 
 def uniform_init(key: Array, pop: int, dim: int, lo: float, hi: float) -> Array:

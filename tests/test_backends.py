@@ -68,24 +68,29 @@ def test_pallas_backend_under_island_engine():
 
 # --- device-resident engine --------------------------------------------------
 
-def test_device_resident_matches_host_stepped():
-    """Same seed -> the single-scan device program and the per-round host loop
-    produce the same incumbent trace and final value."""
-    cfg = IslandConfig(n_islands=2, pop=16, dim=4, sync_every=5, max_evals=4000)
-    r_dev = IslandOptimizer(ALGORITHMS["de"], cfg).minimize(SPHERE, KEY)
-    rounds = []
-    r_host = IslandOptimizer(
-        ALGORITHMS["de"], cfg,
-        round_callback=lambda r, a, v: rounds.append(r),
-    ).minimize(SPHERE, KEY)
-    assert len(rounds) == len(r_host.history) == len(np.asarray(r_dev.history))
-    np.testing.assert_allclose(np.asarray(r_dev.history),
-                               np.asarray(r_host.history), rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(r_dev.value, r_host.value, rtol=1e-5, atol=1e-5)
+@pytest.mark.parametrize("sync_policy", ("barrier", "async"))
+def test_device_resident_matches_host_stepped(sync_policy):
+    """Same seed -> the single-scan device program and the host-stepped
+    BucketStepper (one job, one jit call per round) produce the same
+    incumbent trace, final value and evaluation count, bit for bit."""
+    cfg = IslandConfig(n_islands=2, pop=16, dim=4, sync_every=5, max_evals=4000,
+                       sync_policy=sync_policy)
+    opt = IslandOptimizer(ALGORITHMS["de"], cfg)
+    r_dev = opt.minimize(SPHERE, KEY)
+    stepper = opt.bucket_stepper(SPHERE)
+    state, round_keys = stepper.init(KEY[None])
+    history = []
+    for r in range(stepper.n_rounds):
+        state, point = stepper.step(state, round_keys, r)
+        history.append(np.asarray(point)[0])
+    _, vals = stepper.best(state)
+    np.testing.assert_array_equal(np.asarray(r_dev.history), np.asarray(history))
+    assert float(vals[0]) == r_dev.value
+    assert stepper.evals_done(stepper.n_rounds) == r_dev.n_evals
 
 
 def test_device_resident_single_host_transfer(monkeypatch):
-    """No round_callback -> results cross host<->device exactly once."""
+    """minimize -> results cross host<->device exactly once."""
     pulls = {"n": 0}
     real = jax.device_get
 

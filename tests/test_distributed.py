@@ -283,7 +283,6 @@ def test_island_optimizer_rejects_bad_sharding_configs():
                         mesh=mesh_mod.MeshConfig(devices=1).build(),
                         mesh_cfg=MeshConfig(devices=1))
     opt = IslandOptimizer(ALGORITHMS["de"], _cfg(),
-                          mesh_cfg=MeshConfig(devices=1),
-                          round_callback=lambda r, a, v: None)
-    with pytest.raises(ValueError, match="round_callback"):
-        opt.minimize(f, KEY)
+                          mesh_cfg=MeshConfig(devices=1))
+    with pytest.raises(ValueError, match="unsharded engine"):
+        opt.bucket_stepper(f)

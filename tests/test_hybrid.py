@@ -140,16 +140,21 @@ def test_hybrid_minimize_many_bit_identical():
 
 
 def test_hybrid_host_stepped_matches_device_resident():
+    """The host-stepped BucketStepper (one job) polishes on the resident
+    scan's cadence: same history, value and evaluation count, bit for bit."""
     f = get("sphere")
-    cfg = _island_cfg(**HYBRID)
-    dev = IslandOptimizer(ALGORITHMS["de"], cfg).minimize(f, KEY)
-    seen = []
-    host = IslandOptimizer(ALGORITHMS["de"], cfg,
-                           round_callback=lambda r, a, v: seen.append(r))
-    res = host.minimize(f, KEY)
-    assert res.value == dev.value and res.n_evals == dev.n_evals
-    np.testing.assert_array_equal(np.asarray(dev.history), res.history)
-    assert len(seen) == len(res.history)
+    opt = IslandOptimizer(ALGORITHMS["de"], _island_cfg(**HYBRID))
+    dev = opt.minimize(f, KEY)
+    stepper = opt.bucket_stepper(f)
+    state, round_keys = stepper.init(KEY[None])
+    history = []
+    for r in range(stepper.n_rounds):
+        state, point = stepper.step(state, round_keys, r)
+        history.append(np.asarray(point)[0])
+    _, vals = stepper.best(state)
+    assert float(vals[0]) == dev.value
+    assert stepper.evals_done(stepper.n_rounds) == dev.n_evals
+    np.testing.assert_array_equal(np.asarray(dev.history), np.asarray(history))
 
 
 @pytest.mark.parametrize("method", ("fcg", "avd"))

@@ -143,14 +143,6 @@ def test_bad_request_errors_are_isolated_per_bucket():
     assert sched.poll(ok).status == "done"
 
 
-def test_minimize_many_rejects_round_callback():
-    cfg = IslandConfig(n_islands=1, pop=8, dim=3, max_evals=500)
-    opt = IslandOptimizer(ALGORITHMS["de"], cfg,
-                          round_callback=lambda r, a, v: None)
-    with pytest.raises(ValueError, match="round_callback"):
-        opt.minimize_many(get("sphere"), jnp.stack([jax.random.PRNGKey(0)]))
-
-
 def test_evaluator_cache_returns_same_callable():
     f = get("sphere")
     cfg = ExecutorConfig(backend="xla")
