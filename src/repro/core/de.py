@@ -18,7 +18,11 @@ Key discipline: a generation's ``key`` splits into selection, crossover and
 forced-coordinate keys as in ``_trials``; chunk ``c`` of chunked mode keys on
 ``fold_in(key, c)``. No key reads the population, so chunked mode draws every
 chunk's donors, forced coordinates and crossover key before its chunk loop
-(``_chunk_draws``), and a chunk hashes only its own crossover uniforms.
+(``_chunk_draws``; through ``MetaHeuristic.draw`` the engine makes them for a
+whole round before its scan), and a chunk hashes only its own crossover
+uniforms. Each chunk keeps its rows of those full-population integer draws by
+static slices, since its start is known when tracing; a gather would pick
+them one element at a time on the TPU.
 
 ``fused=True`` routes the whole generation — mutation, crossover, evaluation,
 selection — through the fused ``kernels.de_step`` Pallas kernel (one HBM read /
@@ -113,19 +117,22 @@ def _chunk_draws(key: Array, P: int, D: int, n: int, n_chunks: int
     start_c + n)``, ``start_c = min(c * n, P - n)``, of the P-row draws
     ``_trials`` makes from it (the last chunk overlaps its predecessor when
     ``n`` does not divide ``P``). No key reads the population, so one vmap
-    over ``c`` draws them all before the chunk loop; the starts are static,
-    so the rows are kept by a gather with constant indices (a
-    ``dynamic_slice`` with a batched start would lower to a loop per draw).
-    Returns ``(ra, rb, rc)``, ``jrand`` (each ``(n_chunks, n)``) and the
-    ``n_chunks`` crossover keys."""
-    def draw(c: Array) -> tuple[tuple[Array, ...], Array]:
+    over ``c`` draws them all before the chunk loop. The starts are static,
+    so each chunk's rows are cut by a static slice of its four draws and the
+    slices stacked. Not by a gather with constant indices: the TPU lowers
+    that one element at a time, and it cost more than the chunk's trial and
+    evaluation together. Nor by a ``dynamic_slice`` with a batched start,
+    which lowers to a loop per draw. Returns ``(ra, rb, rc)``, ``jrand``
+    (each ``(n_chunks, n)``) and the ``n_chunks`` crossover keys."""
+    def draw(c: Array) -> tuple[Array, Array]:
         ksel, kcr, kj = jax.random.split(jax.random.fold_in(key, c), 3)
-        return (*_distinct3(ksel, P), jax.random.randint(kj, (P,), 0, D)), kcr
+        return jnp.stack([*_distinct3(ksel, P),
+                          jax.random.randint(kj, (P,), 0, D)]), kcr
 
     rows, kcr = jax.vmap(draw)(jnp.arange(n_chunks))
-    idx = np.minimum(np.arange(n_chunks) * n, P - n)[:, None] + np.arange(n)
-    ra, rb, rc, jrand = (jnp.take_along_axis(r, idx, axis=1) for r in rows)
-    return (ra, rb, rc), jrand, kcr
+    starts = np.minimum(np.arange(n_chunks) * n, P - n)
+    d = jnp.stack([rows[c, :, s:s + n] for c, s in enumerate(starts)])
+    return (d[:, 0], d[:, 1], d[:, 2]), d[:, 3], kcr
 
 
 def _chunk_trials(pop: Array, best: Array, donors: tuple[Array, Array, Array],
@@ -184,9 +191,14 @@ def make(
     csz = max(1, pop // n_chunks) if barrier_mode == "chunked" else pop
     n_eff_chunks = (pop + csz - 1) // csz
 
-    def gen_chunked(state: State, key: Array) -> State:
+    def draw_chunked(key: Array) -> tuple:
         with obs.scope(obs.VARIATION):
-            donors, jrand, kcr = _chunk_draws(key, pop, dim, csz, n_eff_chunks)
+            return _chunk_draws(key, pop, dim, csz, n_eff_chunks)
+
+    def gen_chunked(state: State, key: Array, drawn: tuple | None = None
+                    ) -> State:
+        # ``drawn`` is ``draw_chunked(key)``, made ahead by the engine.
+        donors, jrand, kcr = draw_chunked(key) if drawn is None else drawn
 
         # Later chunks read earlier chunks' already-updated vectors ("stale-ok").
         def body(c: int, carry: tuple[Array, Array]) -> tuple[Array, Array]:
@@ -246,5 +258,6 @@ def make(
     # the evaluator actually runs (parity enforced for every registered
     # policy by tests/test_metaheuristics.py::test_evals_per_gen_parity).
     evals = csz * n_eff_chunks if barrier_mode == "chunked" else pop
+    draw = draw_chunked if gen is gen_chunked and not fused else None
     return MetaHeuristic("de", init, gen, evals_per_gen=evals, init_evals=pop,
-                         step_override=step_override)
+                         step_override=step_override, draw=draw)

@@ -119,14 +119,23 @@ class MetaHeuristic:
     the hook a fused whole-generation kernel (e.g. ``de.make(fused=True)``)
     uses to bypass the pluggable evaluator while keeping init, migration,
     incumbent sharing and budget accounting identical.
+
+    ``draw``, when set (never with ``step_override``), makes the part of a
+    generation's randomness that reads no state: ``gen(state, key)`` equals
+    ``gen(state, key, draw(key))``. The engine then draws a round's
+    generations in one vmap before the round's scan, so the scan body starts
+    on the population, and on the TPU the population's copy into on-chip
+    memory hides behind the draws once a round instead of stalling every
+    generation (chunked DE).
     """
 
     name: str
     init: Callable[[Array], State]          # key -> single-island state
-    gen: Callable[[State, Array], State]    # (state, key) -> state
+    gen: Callable[..., State]               # (state, key[, drawn]) -> state
     evals_per_gen: int
     init_evals: int
     step_override: Callable[[State, Array], State] | None = None
+    draw: Callable[[Array], Any] | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -356,9 +365,11 @@ class IslandOptimizer:
         stacked = cfg.n_islands > 1
         axis, n_shards = self._axis, self._n_shards
         local = self._local
+        draw = None
         if port is None:
             step = (algo.step_override if algo.step_override is not None
                     else algo.gen)
+            draw = algo.draw
 
         def round_fn(state: State, key: Array, step_row: Array | None = None,
                      deliver_row: Array | None = None) -> State:
@@ -376,20 +387,30 @@ class IslandOptimizer:
                 state = {k: v for k, v in state.items()
                          if k not in mig.MAILBOX_KEYS}
 
-            def one_gen(carry: State, k: Array) -> tuple[State, None]:
-                if not stacked:
-                    return step(carry, k), None
+            def island_keys(k: Array) -> Array:
                 # Every shard derives the SAME global (I, 2) key table and
                 # takes its island block, so per-island key streams match
                 # the unsharded engine exactly (determinism contract, §8).
-                ks = local(jax.random.split(k, cfg.n_islands))
+                return local(jax.random.split(k, cfg.n_islands))
+
+            def draw_gen(k: Array) -> Any:
+                return jax.vmap(draw)(island_keys(k)) if stacked else draw(k)
+
+            def one_gen(carry: State, xs: tuple[Array, Any]
+                        ) -> tuple[State, None]:
+                k, drawn = xs
+                args = () if drawn is None else (drawn,)
+                if not stacked:
+                    return step(carry, k, *args), None
+                ks = island_keys(k)
                 if port is not None:
                     return port.step_stacked(carry, ks, br), None
-                return jax.vmap(step)(carry, ks), None
+                return jax.vmap(step)(carry, ks, *args), None
 
             gen_keys = jax.random.split(key, cfg.sync_every)
+            drawn = None if draw is None else jax.vmap(draw_gen)(gen_keys)
             old = state
-            state, _ = jax.lax.scan(one_gen, state, gen_keys)
+            state, _ = jax.lax.scan(one_gen, state, (gen_keys, drawn))
             if self._async:
                 # The step mask is constant across a tick's generations, so
                 # it is applied ONCE after the gens scan, never inside it:

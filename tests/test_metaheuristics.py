@@ -318,6 +318,74 @@ def test_de_chunked_draws_every_chunk_before_the_loop(pop, strategy):
     assert keys <= set(_primitives(jaxpr))
 
 
+@pytest.mark.parametrize("n_islands", [1, 3])
+@pytest.mark.parametrize("strategy", ["rand1bin", "best1bin"])
+@pytest.mark.parametrize("pop", [32, 37])
+def test_de_chunked_keeps_its_draws_rows_without_a_gather(pop, strategy,
+                                                          n_islands):
+    """Each chunk's rows of the integer draws are cut by static slices before
+    the chunk loop, never gathered (the TPU gathers one element at a time):
+    nothing ahead of the loop is a ``gather``, with or without a clamped,
+    overlapping last chunk (pop 37), for one island and three under
+    ``vmap``. After the loop ``track_best`` picks each island's best row,
+    which under ``vmap`` is a gather of one row per island."""
+    from jax.extend.core import Jaxpr
+
+    from repro.core import de
+    f = get("rastrigin")
+    algo = de.make(f, jax.vmap(f.fn), pop, 11, strategy=strategy,
+                   barrier_mode="chunked")
+    keys = jax.random.split(jax.random.PRNGKey(1), n_islands)
+    state = jax.eval_shape(jax.vmap(algo.init), keys)
+    jaxpr = jax.make_jaxpr(jax.vmap(algo.gen))(state, keys).jaxpr
+    (loop,) = [i for i, e in enumerate(jaxpr.eqns)
+               if e.primitive.name in ("scan", "while")]
+    ahead = list(_primitives(Jaxpr(jaxpr.constvars, jaxpr.invars, [],
+                                   jaxpr.eqns[:loop])))
+    assert {"random_bits", "slice"} <= set(ahead), ahead
+    assert "gather" not in ahead, ahead
+
+
+@pytest.mark.parametrize("n_islands", [1, 3])
+@pytest.mark.parametrize("pop", [32, 37])
+def test_de_chunked_round_draws_ahead_bit_identically(pop, n_islands):
+    """Through ``MetaHeuristic.draw`` the engine draws a round's chunk donors
+    and forced coordinates before the round's scan, whose body then draws no
+    random bits; a run is bit-identical to one whose every generation draws
+    its own (``draw`` unset), with a ring between three islands and a
+    clamped, overlapping last chunk at pop 37."""
+    import dataclasses
+
+    from repro.core import de
+    f = get("rastrigin", 11)
+    cfg = IslandConfig(n_islands=n_islands, pop=pop, dim=11, sync_every=3,
+                       migration="ring" if n_islands > 1 else "none",
+                       max_evals=pop * n_islands * 13)
+
+    def maker(ahead: bool):
+        def make(**kw):
+            algo = de.make(barrier_mode="chunked", **kw)
+            assert algo.draw is not None
+            return algo if ahead else dataclasses.replace(algo, draw=None)
+        return make
+
+    runs = []
+    for ahead in (True, False):
+        opt = IslandOptimizer(maker(ahead), cfg)
+        algo = opt._build(f)
+        state = jax.eval_shape(lambda k: opt._init_state(algo, k), KEY)
+        jaxpr = jax.make_jaxpr(opt._round_fn(algo))(state, KEY).jaxpr
+        (scan,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+        inside = set(_primitives(scan.params["jaxpr"].jaxpr))
+        assert ("random_bits" in inside) is not ahead, inside
+        assert "random_bits" in set(_primitives(jaxpr))
+        runs.append(opt.minimize(f, KEY))
+    a, b = runs
+    assert a.value == b.value
+    np.testing.assert_array_equal(np.asarray(a.arg), np.asarray(b.arg))
+    np.testing.assert_array_equal(np.asarray(a.history), np.asarray(b.history))
+
+
 @pytest.mark.parametrize("kind", ["raw", "typed", "rbg"])
 @pytest.mark.parametrize("partitionable", [True, False],
                          ids=["partitionable", "fallback"])

@@ -188,13 +188,15 @@ def test_table1_chunks_build_only_their_own_rows(one_chip):
     gathers donor rows for its own 100 rows alone: no op of the program holds
     the whole population's (800, 1000) bits, and every donor gather yields
     (100, 1000). Every chunk's integer draws are made before the chunk loop,
-    whose body keeps few fusions."""
+    whose body keeps few fusions, and each chunk's rows of them are sliced,
+    not gathered: the program holds no ``s32`` gather."""
     text = _table1_text(one_chip)
     assert f"u32[{P},{D}]" not in text
     gather = r"= (\w+\[[\d,]*\])\S* gather\("
     gathers = re.findall(gather, text)
     donors = [f"f32[{P // 8},{D}]"] * 3
     assert [g for g in gathers if g.startswith("f32")] == donors, gathers
+    assert not [g for g in gathers if g.startswith("s32")], gathers
     body, called = _chunk_loop(text)
     inside = [g for line in body + called for g in re.findall(gather, line)]
     assert inside == donors, inside
@@ -213,7 +215,8 @@ def test_served_ring_compiles_for_four_chips(topo, monkeypatch):
     under ``popt.migrate`` in every round, the merge of the history point is
     an all-reduce under ``popt.sync``, exactly one ``while`` sits directly
     under ``popt.round``, and each chunk of each chip's two islands draws
-    bits and gathers donors for its own 100 rows alone."""
+    bits and gathers donors for its own 100 rows alone, and keeps its rows
+    of the integer draws without a gather."""
     monkeypatch.setattr(mesh_mod.MeshConfig, "build", lambda self: Mesh(
         np.asarray(topo.devices[: self.devices]), (self.axis,)))
     opt, f = build_optimizer(ISLANDS8), get(ISLANDS8.fn, ISLANDS8.dim)
@@ -236,6 +239,7 @@ def test_served_ring_compiles_for_four_chips(topo, monkeypatch):
     gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text)
     assert gathers.count(f"f32[2,{P // 8},{D}]") == 3, gathers
     assert not [g for g in gathers if g.endswith(f"{P},{D}]")], gathers
+    assert f"s32[2,8,{P // 8}]" not in gathers, gathers
     pop_copies = [line for line in _chunk_loop(text)[0]
                   if re.search(rf"= \(f32\[1,2,{P},{D}\].* copy-start\(", line)]
     assert not pop_copies, pop_copies
